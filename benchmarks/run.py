@@ -25,6 +25,7 @@ TABLES = [
 
 def main() -> None:
     print("name,us_per_call,derived")
+    failed = []
     for title, fn in TABLES:
         print(f"# --- {title} ---", flush=True)
         t0 = time.time()
@@ -32,7 +33,10 @@ def main() -> None:
             fn()
         except Exception as e:  # keep the suite going; report the failure
             print(f"{title},-1,FAILED:{type(e).__name__}:{e}", flush=True)
+            failed.append(title)
         print(f"# {title} done in {time.time() - t0:.0f}s", flush=True)
+    if failed:
+        sys.exit(f"{len(failed)} table(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

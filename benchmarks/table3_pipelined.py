@@ -40,7 +40,7 @@ def main():
         sched = make_schedule("ddpm_linear", n)
         r = run_pair(model_fn, sched, SolverConfig("ddim"), x0,
                      SRDSConfig(tol=1e-3, num_blocks=b))
-        env = dict(os.environ,
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={b}",
                    PYTHONPATH="src")
         out = subprocess.run([sys.executable, "-c", CODE.format(n=n, b=b)],

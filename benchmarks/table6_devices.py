@@ -87,13 +87,16 @@ print(json.dumps({{"t": sorted(ts)[1], "iters": int(jnp.max(res.iterations)),
 
 
 def _run(code: str, devices: int):
-    env = dict(os.environ,
+    # the child runs on fake CPU devices: pinned to CPU so it never reaches
+    # for an accelerator that this (or another) process already holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env)
     if out.returncode != 0:
-        return None
+        raise RuntimeError(f"table6 subprocess ({devices} devices) failed:\n"
+                           f"{out.stderr[-4000:]}")
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
@@ -101,8 +104,6 @@ def mesh_row():
     """The (time, data, model) DiT row for BENCH_core.json."""
     t, d, m = MESH_SHAPE
     r = _run(MESH_CODE.format(shape=MESH_SHAPE), devices=t * d * m)
-    if r is None:
-        raise RuntimeError("table6 mesh subprocess failed")
     name = f"table6/mesh_t{t}d{d}m{m}"
     emit(name, r["t"] * 1e6,
          f"iters={r['iters']};max_abs_diff={r['max_abs_diff']:.2e};"
@@ -116,7 +117,7 @@ def mesh_row():
 
 def main(out: str = None):
     for dev in (1, 2, 4):
-        r = _run(CODE.format(d=dev), devices=dev) or {"t": -1, "iters": -1}
+        r = _run(CODE.format(d=dev), devices=dev)
         emit(f"table6/devices{dev}", r["t"] * 1e6,
              f"iters={r['iters']};wallclock_s={r['t']:.3f}")
     return merge_out(out, [mesh_row()], "pinned_table6",

@@ -104,7 +104,8 @@ print(f"serving engine:   {len(reqs)} mixed-tol requests, batch 4 -> "
 
 
 def main():
-    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", CODE], env=env)
     sys.exit(r.returncode)

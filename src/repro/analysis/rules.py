@@ -84,7 +84,7 @@ _DRIFTED_TREE_UTIL = {
     "tree_all": "repro.compat.tree (extend the shim)",
     "tree_reduce": "repro.compat.tree (extend the shim)",
 }
-# Any touch of the legacy shard_map module is drifted (moved in 0.5).
+# Any touch of the old experimental shard_map module is drifted.
 _DRIFTED_PREFIXES = ("jax.experimental.shard_map",)
 
 _RL001_ALLOWED = ("src/repro/compat.py",)
@@ -120,8 +120,8 @@ def rl001_compat_drift(mod: ModuleInfo) -> Iterable[Finding]:
         seen.add(key)
         return _find(mod, node, "RL001", "compat-drift",
                      f"drifted JAX API `{qn}` outside repro.compat — use "
-                     f"`{blessed}` (ROADMAP standing policy: supported JAX "
-                     f"range 0.4.x through >=0.5)")
+                     f"`{blessed}` (ROADMAP standing policy: drifting JAX "
+                     f"APIs are named only in repro.compat)")
 
     for node in ast.walk(mod.tree):
         # import statements that bind a drifted name (aliased or not)
@@ -865,9 +865,9 @@ def rl008_model_eval_seam(mod: ModuleInfo) -> Iterable[Finding]:
             mod, node, "RL008", "model-eval-seam",
             f"direct model eval `{leaf}(x, t)` outside the Denoiser seam — "
             f"adapt via repro.core.denoiser.as_denoiser and evaluate "
-            f"through the Denoiser (standalone call, .inner_eval() inside "
-            f"a driver shard_map, or .shard_eval() under denoiser_spec) so "
-            f"time/data/model parallelism compose driver-free")
+            f"through the Denoiser (standalone call, or .inner_eval() "
+            f"inside a driver shard_map) so time/data/model parallelism "
+            f"compose driver-free")
 
 
 # ==========================================================================

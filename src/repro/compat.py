@@ -1,24 +1,21 @@
-"""Version-adaptive JAX compatibility substrate.
+"""The one place the repo touches JAX APIs that drift between releases.
 
-JAX's public surface drifts between minor releases: ``shard_map`` moved
+JAX's public surface moves between minor releases: ``shard_map`` moved
 from ``jax.experimental.shard_map`` to ``jax.shard_map`` and renamed its
 replication-check kwarg ``check_rep`` -> ``check_vma``; ``jax.make_mesh``
-grew an ``axis_types=`` kwarg (with ``jax.sharding.AxisType``) that older
-releases reject; ``jax.tree`` aliases ``jax.tree_util``.  Hard-coding any
-one release's spelling makes the repo dead on every other release.
+grew ``axis_types=``; the Pallas compiler-params dataclasses were
+renamed; ``cost_analysis()`` changed its return shape.  Scattering one
+release's spelling over the tree makes every such move a repo-wide edit.
 
 Policy (see ROADMAP.md): **never call drifted JAX APIs directly — go
-through ``repro.compat``**.  Each wrapper resolves the installed API *at
-call time* by introspecting what the runtime actually provides, so a
-single source tree runs unmodified on JAX 0.4.x and ≥0.5.
-
-Wrappers use the *modern* spelling (``check_vma``, ``axis_types``) and
-translate downward; new code should read like new-JAX code.
+through ``repro.compat``** (enforced by reprolint rule RL001).  The
+wrappers are written for the pinned installation (``requirements.txt``:
+jax/jaxlib 0.9.0, libtpu 0.0.34) and nothing older; when the pin moves,
+this module is the only file that changes.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence
+from typing import Any, Sequence
 
 import jax
 
@@ -35,223 +32,64 @@ __all__ = [
 ]
 
 
-# --------------------------------------------------------------------------
-# shard_map: jax.shard_map (>=0.5, check_vma=) vs
-#            jax.experimental.shard_map.shard_map (0.4.x, check_rep=)
-# --------------------------------------------------------------------------
-
-def _raw_shard_map():
-    """The installed shard_map callable, wherever this JAX hides it."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        return impl
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy
-
-
-def _replication_check_kwarg(impl) -> Optional[str]:
-    """Name of the replication-check kwarg accepted by ``impl`` (or None)."""
-    try:
-        params = inspect.signature(impl).parameters
-    except (TypeError, ValueError):
-        return None
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return name
-    return None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None, **kwargs):
-    """Blessed ``shard_map``: modern kwargs, any JAX.
-
-    ``check_vma`` is translated to whatever replication-check kwarg the
-    installed implementation takes (``check_vma`` on new JAX, ``check_rep``
-    on 0.4.x); pass ``None`` to use the implementation's default.  Extra
-    kwargs are forwarded verbatim.
-    """
-    impl = _raw_shard_map()
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default.  Extra
+    kwargs are forwarded verbatim."""
     if check_vma is not None:
-        kw = _replication_check_kwarg(impl)
-        if kw is not None:
-            kwargs[kw] = check_vma
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
-
-# --------------------------------------------------------------------------
-# mesh construction: axis_types=AxisType.Auto exists only on new JAX
-# --------------------------------------------------------------------------
 
 def axis_type_auto():
-    """``jax.sharding.AxisType.Auto`` on new JAX, else ``None``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return getattr(axis_type, "Auto", None) if axis_type is not None else None
+    """``jax.sharding.AxisType.Auto``."""
+    return jax.sharding.AxisType.Auto
 
 
 def default_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n_axes`` when the enum exists, else ``None``."""
-    auto = axis_type_auto()
-    return None if auto is None else (auto,) * n_axes
-
-
-def _raw_make_mesh():
-    return getattr(jax, "make_mesh", None)
+    """``(AxisType.Auto,) * n_axes`` — every axis auto-sharded."""
+    return (axis_type_auto(),) * n_axes
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None, axis_types: Any = "auto"):
-    """Blessed mesh constructor.
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` by default.
 
     ``axis_types="auto"`` (the default) requests ``AxisType.Auto`` on every
-    axis *when the installed JAX understands axis types* and is silently
-    dropped otherwise — this matches old-JAX behavior, where every mesh
-    axis is implicitly auto-sharded.  Pass ``None`` to never send the
-    kwarg, or an explicit tuple to forward it (ignored if unsupported).
+    axis; pass ``None`` for JAX's own default, or an explicit tuple.
     """
-    impl = _raw_make_mesh()
-    if impl is None:
-        # Pre-make_mesh JAX: reshape the device list by hand.
-        import numpy as np
-        from jax.sharding import Mesh
-        devs = list(jax.devices()) if devices is None else list(devices)
-        return Mesh(np.asarray(devs).reshape(tuple(axis_shapes)),
-                    tuple(axis_names))
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    try:
-        params = inspect.signature(impl).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "axis_types" in params:
-        if axis_types == "auto":
-            axis_types = default_axis_types(len(tuple(axis_names)))
-        if axis_types is not None:
-            kwargs["axis_types"] = axis_types
-    return impl(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    if axis_types == "auto":
+        axis_types = default_axis_types(len(tuple(axis_names)))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, devices=devices)
 
-
-# --------------------------------------------------------------------------
-# named-axis introspection: jax.lax.axis_size is a newer addition
-# --------------------------------------------------------------------------
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, inside shard_map/pmap.
+    """Static size of a named mesh axis, inside shard_map/pmap."""
+    return jax.lax.axis_size(axis_name)
 
-    New JAX spells this ``jax.lax.axis_size``; on 0.4.x, ``psum`` of the
-    literal 1 constant-folds to the same static Python int.
-    """
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# --------------------------------------------------------------------------
-# Pallas TPU compiler params: TPUCompilerParams (0.4.x) -> CompilerParams
-# --------------------------------------------------------------------------
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` across the rename.
-
-    New JAX calls the dataclass ``CompilerParams``; 0.4.x spells it
-    ``TPUCompilerParams`` (same fields).  Kernels must build it through
-    here — the interpret-mode path still constructs the object at trace
-    time, so the wrong name breaks CPU test runs, not just TPU.
-    """
+    """``pltpu.CompilerParams(**kwargs)`` — the Mosaic options dataclass
+    every TPU kernel builds through here."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def gpu_compiler_params(**kwargs):
-    """``pltriton.CompilerParams(**kwargs)`` across the same rename.
-
-    The Triton lowering's options dataclass (``num_warps``,
-    ``num_stages``) is ``TritonCompilerParams`` on 0.4.x/0.5.x and
-    ``CompilerParams`` on newer JAX — the mirror image of the TPU
-    rename above.  Kernels must build it through here."""
+    """``pltriton.CompilerParams(**kwargs)`` — the Triton options
+    dataclass (``num_warps``, ``num_stages``)."""
     from jax.experimental.pallas import triton as pltriton
-    cls = getattr(pltriton, "CompilerParams", None)
-    if cls is None:
-        cls = pltriton.TritonCompilerParams
-    return cls(**kwargs)
+    return pltriton.CompilerParams(**kwargs)
 
-
-# --------------------------------------------------------------------------
-# compiled-program introspection: cost_analysis() drifted list[dict] -> dict
-# --------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a flat dict.
-
-    JAX 0.4.x returns a one-element list of per-program dicts; newer JAX
-    returns the dict directly (and may return None for unsupported
-    backends).  Callers always get a (possibly empty) dict.
-    """
+    """``compiled.cost_analysis()`` as a (possibly empty) dict: backends
+    without a cost model return ``None``."""
     cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if len(cost) else {}
-    return dict(cost)
+    return dict(cost) if cost else {}
 
 
-# --------------------------------------------------------------------------
-# pytree utilities: jax.tree is the modern alias of jax.tree_util
-# --------------------------------------------------------------------------
-
-def _tree_module():
-    mod = getattr(jax, "tree", None)
-    if mod is not None and hasattr(mod, "map"):
-        return mod
-    return jax.tree_util
-
-
-class _TreeShim:
-    """``jax.tree``-shaped facade over whichever tree module exists."""
-
-    @staticmethod
-    def map(f, tree_, *rest, **kwargs):
-        mod = _tree_module()
-        fn = getattr(mod, "map", None) or mod.tree_map
-        return fn(f, tree_, *rest, **kwargs)
-
-    @staticmethod
-    def flatten(tree_, *args, **kwargs):
-        mod = _tree_module()
-        fn = getattr(mod, "flatten", None) or mod.tree_flatten
-        return fn(tree_, *args, **kwargs)
-
-    @staticmethod
-    def unflatten(treedef, leaves):
-        mod = _tree_module()
-        fn = getattr(mod, "unflatten", None) or mod.tree_unflatten
-        return fn(treedef, leaves)
-
-    @staticmethod
-    def leaves(tree_, *args, **kwargs):
-        mod = _tree_module()
-        fn = getattr(mod, "leaves", None) or mod.tree_leaves
-        return fn(tree_, *args, **kwargs)
-
-    @staticmethod
-    def structure(tree_, *args, **kwargs):
-        mod = _tree_module()
-        fn = getattr(mod, "structure", None) or mod.tree_structure
-        return fn(tree_, *args, **kwargs)
-
-    @staticmethod
-    def map_with_path(f, tree_, *rest, **kwargs):
-        # jax.tree.map_with_path only landed in 0.5.x; the tree_util
-        # spelling exists across the whole supported range
-        mod = _tree_module()
-        fn = getattr(mod, "map_with_path", None) \
-            or jax.tree_util.tree_map_with_path
-        return fn(f, tree_, *rest, **kwargs)
-
-
-tree = _TreeShim()
+# pytree utilities: compat.tree.map / flatten / unflatten / leaves /
+# structure / map_with_path are jax.tree's own functions
+tree = jax.tree

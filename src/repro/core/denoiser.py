@@ -25,13 +25,14 @@ the drivers' time/data parallelism in three ways, all driver-agnostic:
    hits (vmap-of-shard_map over blocks);
 2. **inner** (``den.inner_eval()``): for call sites already inside a
    driver ``shard_map`` whose in/out specs *replicate* over the model
-   axes (the sharded/wavefront drivers).  The mesh axes are still bound
-   inside the enclosing body, so the glue slices the replicated operand
-   per ``in_spec``, runs ``shard_fn``, and all-gathers per ``out_spec``;
-3. **shard** (``den.shard_eval()``): for bodies whose specs already
-   shard the operand per ``in_spec`` (the serve engine's fine program via
-   ``parallel.sharding.denoiser_spec``) — ``shard_fn`` applies directly,
-   no per-eval collectives beyond the backbone's own.
+   axes (the sharded/wavefront drivers, and the serving engine's
+   coarse sweep under a mesh).  The mesh axes are still bound inside the
+   enclosing body, so the glue slices the replicated operand per
+   ``in_spec``, runs ``shard_fn``, and all-gathers per ``out_spec``;
+3. **shard** (``den.shard_eval()``): for operands already sliced per
+   ``in_spec`` (the serving engine's fine solves, sliced once per solve
+   to ``parallel.sharding.denoiser_spec``) — ``shard_fn`` applies
+   directly, no per-eval collectives beyond the backbone's own.
 """
 from __future__ import annotations
 
@@ -43,7 +44,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
 
-__all__ = ["Denoiser", "as_denoiser"]
+__all__ = ["Denoiser", "as_denoiser", "gather_spec", "slice_spec"]
 
 
 def _spec_axes(spec):
@@ -60,7 +61,7 @@ def _spec_axes(spec):
     return out
 
 
-def _slice_spec(x, spec):
+def slice_spec(x, spec):
     """The local ``spec``-shard of a replicated ``x`` (inside shard_map)."""
     for dim, name in _spec_axes(spec):
         n = compat.axis_size(name)
@@ -74,7 +75,7 @@ def _slice_spec(x, spec):
     return x
 
 
-def _gather_spec(y, spec):
+def gather_spec(y, spec):
     """Reassemble the global array from ``spec``-shards (inside shard_map)."""
     for dim, name in _spec_axes(spec):
         y = jax.lax.all_gather(y, name, axis=dim, tiled=True)
@@ -147,13 +148,13 @@ class Denoiser:
         shard_fn, in_spec, out_spec = self.shard_fn, self.in_spec, self.out_spec
 
         def eval_fn(x, t):
-            return _gather_spec(shard_fn(_slice_spec(x, in_spec), t), out_spec)
+            return gather_spec(shard_fn(slice_spec(x, in_spec), t), out_spec)
 
         return eval_fn
 
     def shard_eval(self) -> Callable:
-        """Eval callable for inside a shard_map whose specs already shard
-        the operand per ``in_spec`` (see ``parallel.sharding.denoiser_spec``)."""
+        """Eval callable for operands already sliced per ``in_spec``
+        (see ``parallel.sharding.denoiser_spec``)."""
         return self.shard_fn if self.is_model_parallel else self.fn
 
 

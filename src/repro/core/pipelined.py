@@ -29,8 +29,8 @@ Two TPU-native implementations of the paper's parallelism:
 Both functions are written against a *local* (per-shard) view and must be
 called inside ``shard_map``; ``make_*_sampler`` wrappers build the jitted
 SPMD program for a given mesh via :func:`repro.compat.shard_map` (the
-version-adaptive surface — JAX moved ``shard_map`` between 0.4.x and 0.5,
-so no call site here names a ``jax.*`` spelling directly).
+one module that names drifting JAX APIs, so no call site here names a
+``jax.*`` spelling directly).
 """
 from __future__ import annotations
 

@@ -29,6 +29,12 @@ LANES = 128
 # compiled backends) — resolve once, then pad and launch with the same
 # value.
 TILE_ROWS = 256
+# Per-tile reduction partials are written as one lane-dense (8, 128) f32
+# tile per grid step (the scalar broadcast across it): Mosaic stores no
+# scalars to VMEM and requires the last two block dims to be (8, 128)
+# multiples, so a (1, 1) partial block does not compile for the chip.  The
+# entry points slice element [0, 0] of each tile back out.
+PARTIAL_TILE = (8, LANES)
 
 
 def _ddim_kernel(x_ref, e_ref, ab_ref, o_ref):
@@ -67,7 +73,7 @@ def _parareal_resid_kernel(y_ref, c_ref, p_ref, x_ref, o_ref, r_ref):
     xo = x_ref[...].astype(jnp.float32)
     out = y + c - p
     o_ref[...] = out.astype(o_ref.dtype)
-    r_ref[0, 0] = jnp.sum(jnp.abs(out - xo))
+    r_ref[...] = jnp.full(r_ref.shape, jnp.sum(jnp.abs(out - xo)), jnp.float32)
 
 
 def parareal_update_residual_pallas(y2d, c2d, p2d, x2d, *,
@@ -85,7 +91,7 @@ def parareal_update_residual_pallas(y2d, c2d, p2d, x2d, *,
     r = y2d.shape[0]
     br = min(block_rows, r)
     tiles = pl.cdiv(r, br)
-    return pl.pallas_call(
+    out, partials = pl.pallas_call(
         _parareal_resid_kernel,
         grid=(tiles,),
         in_specs=[
@@ -96,15 +102,16 @@ def parareal_update_residual_pallas(y2d, c2d, p2d, x2d, *,
         ],
         out_specs=[
             pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1,) + PARTIAL_TILE, lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(y2d.shape, y2d.dtype),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.float32),
+            jax.ShapeDtypeStruct((tiles,) + PARTIAL_TILE, jnp.float32),
         ],
         interpret=interpret,
         name="srds_parareal_update_residual",
     )(y2d, c2d, p2d, x2d)
+    return out, partials[:, 0, :1]
 
 
 def _parareal_kernel(y_ref, c_ref, p_ref, o_ref, r_ref):
@@ -112,7 +119,7 @@ def _parareal_kernel(y_ref, c_ref, p_ref, o_ref, r_ref):
     c = c_ref[...].astype(jnp.float32)
     p = p_ref[...].astype(jnp.float32)
     o_ref[...] = (y + c - p).astype(o_ref.dtype)
-    r_ref[0, 0] = jnp.sum(jnp.abs(c - p))
+    r_ref[...] = jnp.full(r_ref.shape, jnp.sum(jnp.abs(c - p)), jnp.float32)
 
 
 def parareal_update_pallas(y2d, c2d, p2d, *, block_rows=TILE_ROWS,
@@ -124,7 +131,7 @@ def parareal_update_pallas(y2d, c2d, p2d, *, block_rows=TILE_ROWS,
     r = y2d.shape[0]
     br = min(block_rows, r)
     tiles = pl.cdiv(r, br)
-    return pl.pallas_call(
+    out, partials = pl.pallas_call(
         _parareal_kernel,
         grid=(tiles,),
         in_specs=[
@@ -134,12 +141,13 @@ def parareal_update_pallas(y2d, c2d, p2d, *, block_rows=TILE_ROWS,
         ],
         out_specs=[
             pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1,) + PARTIAL_TILE, lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(y2d.shape, y2d.dtype),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.float32),
+            jax.ShapeDtypeStruct((tiles,) + PARTIAL_TILE, jnp.float32),
         ],
         interpret=interpret,
         name="srds_parareal_update",
     )(y2d, c2d, p2d)
+    return out, partials[:, 0, :1]

@@ -116,12 +116,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
         keep = _mask(bq, bk, iq, ik, sq, sk, causal, window)
         s = jnp.where(keep, s, NEG_INF)
         m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # guard fully-masked rows: m_new == NEG_INF would give exp(0) == 1
-        p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1)
-        acc[...] = acc[...] * alpha[:, None] + jax.lax.dot(
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc[...] = acc[...] * alpha + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
@@ -129,7 +129,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
     def _finalize():
         l = l_sc[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows -> 0
-        o_ref[0] = (acc[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / l_safe).astype(o_ref.dtype)
         lse_ref[0] = m_sc[...] + jnp.log(l_safe)
 
 
@@ -422,7 +422,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                window=window, sq=sq, sk=sk, bq=bq, bk=bk)
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -430,24 +430,27 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
             pl.BlockSpec((1, bk, d), lambda b, iq, ik, g=group: (b // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda b, iq, ik, g=group: (b // g, ik, 0)),
         ],
+        # the LSE leaves as a (bh, sq, 1) column (sliced back to (bh, sq)):
+        # Mosaic refuses a (1, bq) block of a (bh, sq) array
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik: (b, iq)),
+            pl.BlockSpec((1, bq, 1), lambda b, iq, ik: (b, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="srds_flash_fwd",
     )(q, k, v)
+    return o, lse[..., 0]
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -474,16 +477,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = _clean(k_ref[0].astype(jnp.float32), kv_valid)
         v = _clean(v_ref[0].astype(jnp.float32), kv_valid)
         do = _clean(do_ref[0].astype(jnp.float32), q_valid)
-        lse = jnp.where(q_valid[:, 0], lse_ref[0], 0.0)
-        delta = jnp.where(q_valid[:, 0], delta_ref[0], 0.0)
+        lse = jnp.where(q_valid, lse_ref[0], 0.0)
+        delta = jnp.where(q_valid, delta_ref[0], 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         keep = _mask(bq, bk, iq, ik, sq, sk, causal, window)
         s = jnp.where(keep, s, NEG_INF)
-        p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = jnp.where(keep, p * (dp - delta[:, None]) * scale, 0.0)
+        ds = jnp.where(keep, p * (dp - delta) * scale, 0.0)
         dq_acc[...] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
@@ -517,18 +520,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = _clean(k_ref[0].astype(jnp.float32), kv_valid)
         v = _clean(v_ref[0].astype(jnp.float32), kv_valid)
         do = _clean(do_ref[0].astype(jnp.float32), q_valid)
-        lse = jnp.where(q_valid[:, 0], lse_ref[0], 0.0)
-        delta = jnp.where(q_valid[:, 0], delta_ref[0], 0.0)
+        lse = jnp.where(q_valid, lse_ref[0], 0.0)
+        delta = jnp.where(q_valid, delta_ref[0], 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         keep = _mask(bq, bk, iq, ik, sq, sk, causal, window)
         s = jnp.where(keep, s, NEG_INF)
-        p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)   # (bq, bk)
+        p = jnp.where(keep, jnp.exp(s - lse), 0.0)   # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = jnp.where(keep, p * (dp - delta[:, None]) * scale, 0.0)
+        ds = jnp.where(keep, p * (dp - delta) * scale, 0.0)
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -561,6 +564,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
                               compiler_params=_gpu_params(num_warps,
                                                           num_stages),
                               interpret=interpret)
+    # the TPU family keeps per-row statistics as (bq, 1) columns: a (1, bq)
+    # block of a (bh, sq) array breaks Mosaic's (8, 128) block rule
+    lse3, delta3 = lse[..., None], delta[..., None]
 
     kq = functools.partial(_dq_kernel, scale=scale, causal=causal,
                            window=window, sq=sq, sk=sk, bq=bq, bk=bk)
@@ -572,8 +578,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
             pl.BlockSpec((1, bk, d), lambda b, iq, ik, g=group: (b // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda b, iq, ik, g=group: (b // g, ik, 0)),
             pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bq), lambda b, iq, ik: (b, iq)),
+            pl.BlockSpec((1, bq, 1), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, iq, ik: (b, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
@@ -582,7 +588,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="srds_flash_dq",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse3, delta3)
 
     kkv = functools.partial(_dkv_kernel, scale=scale, causal=causal,
                             window=window, sq=sq, sk=sk, bq=bq, bk=bk)
@@ -594,8 +600,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
             pl.BlockSpec((1, bk, d), lambda b, ik, iq, g=group: (b // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda b, ik, iq, g=group: (b // g, ik, 0)),
             pl.BlockSpec((1, bq, d), lambda b, ik, iq: (b, iq, 0)),
-            pl.BlockSpec((1, bq), lambda b, ik, iq: (b, iq)),
-            pl.BlockSpec((1, bq), lambda b, ik, iq: (b, iq)),
+            pl.BlockSpec((1, bq, 1), lambda b, ik, iq: (b, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, ik, iq: (b, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, ik, iq: (b, ik, 0)),
@@ -611,5 +617,5 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="srds_flash_dkv",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse3, delta3)
     return dq, dk, dv

@@ -43,6 +43,9 @@ def run_one(arch, shape, mesh, out_dir, timeout, extra_args=()):
     # (the optimized profile is forwarded via extra_args below)
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # the dry run compiles on placeholder CPU devices: pin the child to
+    # CPU so it never contends for an accelerator another process holds
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.time()
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,

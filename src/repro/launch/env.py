@@ -26,13 +26,23 @@ half-apply (an env var mutated after init is a silent no-op — the
 worst failure mode for a performance preset).  Benchmark emitters call
 it from their ``--platform`` / ``--host-devices`` CLI flags before any
 device work (see docs/benchmarks.md).
+
+``configure_compile_cache`` places JAX's persistent compilation cache:
+where ``JAX_COMPILATION_CACHE_DIR`` points if it is set (JAX reads that
+variable itself), else at the fixed in-checkout path :data:`CACHE_DIR`.
+The path is part of the cache key, so it never moves between runs.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Optional
 
-__all__ = ["GPU_PERF_FLAGS", "GPU_RUNTIME_ENV", "configure_platform"]
+__all__ = ["CACHE_DIR", "GPU_PERF_FLAGS", "GPU_RUNTIME_ENV",
+           "configure_compile_cache", "configure_platform"]
+
+# the checkout's own persistent-compilation-cache directory (gitignored)
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # the XLA GPU performance preset (upstream gpu_performance_tips set):
 # fusion + async collectives + latency hiding, for serving-shaped work
@@ -161,3 +171,19 @@ def configure_platform(platform: Optional[str] = None,
         import jax
         jax.config.update("jax_platform_name", platform)
     return {"platform": platform, "xla_flags": xla_flags, "env": env}
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and this
+    sets nothing.  Otherwise the cache goes to :data:`CACHE_DIR`, the same
+    path inside the checkout on every run.  Call it from a script's
+    ``main()`` before the first compile, never at import time.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)

@@ -6,9 +6,8 @@ dry-run forces 512 placeholder devices via XLA_FLAGS before first init,
 while tests and benches must keep seeing the single real device.
 
 Meshes are built through :func:`repro.compat.make_mesh` (never
-``jax.make_mesh`` directly): the ``axis_types=AxisType.Auto`` kwarg only
-exists on newer JAX, and the compat layer requests it when available while
-degrading cleanly on 0.4.x, where every mesh axis is implicitly auto.
+``jax.make_mesh`` directly), which requests ``axis_types=AxisType.Auto``
+on every axis.
 """
 from __future__ import annotations
 

@@ -11,8 +11,7 @@ wire format is 4x smaller than fp32 / 2x smaller than bf16.
 attention outputs computed over disjoint KV-sequence slices using their
 logsumexps (used by the model-axis-sharded decode path in repro.serve).
 
-Pytree plumbing goes through :data:`repro.compat.tree` (the ``jax.tree``
-alias only exists on newer JAX; ``jax.tree_util`` is the 0.4.x spelling).
+Pytree plumbing goes through :data:`repro.compat.tree` (reprolint RL001).
 """
 from __future__ import annotations
 

@@ -212,8 +212,8 @@ def microbatch_spec(data_axis: str, *, mesh=None) -> P:
 def denoiser_spec(data_axis: Optional[str], denoiser=None, *, mesh=None) -> P:
     """Block-heads spec composing the data axis with a denoiser's model axes.
 
-    The serving engine's fine program maps a ``(B, K, *sample)`` heads
-    tensor; :func:`microbatch_spec` shards K over ``data_axis``.  A
+    The serving engine's fine solves map a ``(B, K, *sample)`` heads
+    tensor; :func:`microbatch_spec` splits K over ``data_axis``.  A
     sharding-aware :class:`repro.core.denoiser.Denoiser` additionally
     shards *sample* dims over its own mesh axes (``in_spec``, e.g. DiT
     patch rows over ``model``), so the composed spec is::
@@ -224,11 +224,12 @@ def denoiser_spec(data_axis: Optional[str], denoiser=None, *, mesh=None) -> P:
     (the denoiser's ``in_spec`` is over the sample layout
     ``(K, *sample_shape)``; its leading K entry — replicated by
     convention — is dropped and the remaining entries shift right by one
-    to land on the heads tensor's sample dims).  Inside the shard_map
-    body the denoiser evaluates via ``shard_eval()`` — its per-shard
-    ``shard_fn`` directly, no per-eval slice/gather glue — which is how
-    the block ``time`` axis, the ``data`` axis and the ``model`` axis
-    compose into one (time, data, model) mesh
+    to land on the heads tensor's sample dims).  The engine slices each
+    fine solve's heads to this spec once, runs every eval of the solve as
+    the denoiser's ``shard_eval()`` — its per-shard ``shard_fn`` directly,
+    no per-eval slice/gather glue — and gathers the result once.  That is
+    how the block ``time`` axis, the ``data`` axis and the ``model`` axis
+    compose on one (time, data, model) mesh
     (:func:`repro.launch.mesh.make_srds_mesh` builds it).
 
     With ``denoiser=None`` (or a plain adapted fn) this degrades to

@@ -99,23 +99,30 @@ What the engine does / does not guarantee:
   ``submit`` therefore **rejects** ``ddpm`` requests unless the engine was
   built with ``allow_inexact=True`` (an explicit caller opt-in).
 
-Parallelism hooks (both ride :mod:`repro.compat` wrappers):
+Parallelism hooks (both ride :mod:`repro.compat` wrappers).  With a
+mesh, each compiled program is one ``shard_map`` over it whose operands
+and results are replicated; only the fine solves split across devices:
 
-* ``axis`` — shard the *block* dim of each refinement's fine solves
-  (``shard_map`` + one ``all_gather`` per iteration, the
+* ``axis`` — each device solves its slice of the *block* dim, and one
+  tiled ``all_gather`` per iteration re-joins them (the
   :func:`repro.core.pipelined.srds_sharded_local` layout);
-* ``data_axis`` — shard the *slot batch* (K) over a data mesh axis: lanes
-  are independent, so the fine solves split with no collectives at all
-  (specs from :func:`repro.parallel.sharding.microbatch_spec`).  Both
-  axes compose on a 2D mesh.
+* ``data_axis`` — each device solves its slice of the *slot batch* (K):
+  lanes are independent, so one ``all_gather`` re-joins them.  Both axes
+  compose on a 2D mesh.
+
+The coarse sweep and the corrector run on every device's replica, over
+all K lanes, so their Pallas kernels stay per-device calls (Mosaic
+kernels cannot be partitioned by XLA).
 
 Model evals go through the :class:`repro.core.denoiser.Denoiser` seam: a
 model-parallel denoiser (e.g. the patch-sharded DiT from
 :func:`repro.models.dit.make_denoiser`) contributes its own ``in_spec``
-sample axes to the fine program's specs via
-:func:`repro.parallel.sharding.denoiser_spec`, so time x data x model all
-compose on one 3D mesh (:func:`repro.launch.mesh.make_srds_mesh`) with
-zero engine-specific model code.
+sample axes to the fine solves' heads spec
+(:func:`repro.parallel.sharding.denoiser_spec`): each solve's heads are
+sliced to it once and every eval runs ``shard_eval`` directly, while the
+coarse sweep evals in the ``inner_eval`` form — so time x data x model
+all compose on one 3D mesh (:func:`repro.launch.mesh.make_srds_mesh`)
+with zero engine-specific model code.
 """
 from __future__ import annotations
 
@@ -126,6 +133,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.analysis.markers import hot_loop
@@ -137,11 +145,11 @@ from repro.core.engine import (IterationCost, blockwise_norm,
                                prefix_frontier, resolve_blocks,
                                resolve_fused, suffix_refinement,
                                truncated_evals)
-from repro.core.denoiser import as_denoiser
+from repro.core.denoiser import as_denoiser, gather_spec, slice_spec
 from repro.core.schedules import DiffusionSchedule, make_schedule
 from repro.core.solvers import ModelFn, SolverConfig, solve, solver_names
 from repro.core.window import FixedBudget, resolve_policy
-from repro.parallel.sharding import denoiser_spec, microbatch_spec
+from repro.parallel.sharding import denoiser_spec
 
 __all__ = ["SampleRequest", "SampleResponse", "CompletionRecord",
            "DiffusionSamplingEngine", "IterationEMA"]
@@ -721,19 +729,15 @@ class DiffusionSamplingEngine:
                  window=None, clock: Optional[Clock] = None, accel=None):
         self.model_fn = model_fn
         # every model eval goes through the sharding-aware Denoiser seam;
-        # plain callables adapt for free (replicated specs).  A
-        # model-parallel denoiser is bound to the engine mesh so the coarse
-        # sweep / corrector (outside any shard_map) self-wrap its shard_fn.
+        # plain callables adapt for free (replicated specs).  Under an
+        # engine mesh a model-parallel denoiser evaluates inside the
+        # programs' shard_map (see _spmd); without one it self-wraps over
+        # its own bound mesh.
         den = as_denoiser(model_fn)
-        if den.is_model_parallel:
-            if mesh is not None:
-                den.check_mesh(mesh)
-                if den.mesh is None:
-                    den = den.bind(mesh)
-            elif den.mesh is None:
-                raise ValueError(
-                    "model-parallel denoiser needs a mesh: pass mesh= to "
-                    "the engine or bind one with Denoiser.bind(mesh)")
+        if den.is_model_parallel and mesh is None and den.mesh is None:
+            raise ValueError(
+                "model-parallel denoiser needs a mesh: pass mesh= to "
+                "the engine or bind one with Denoiser.bind(mesh)")
         self.denoiser = den
         self.sample_shape = tuple(sample_shape)
         self.solver = solver
@@ -782,10 +786,16 @@ class DiffusionSamplingEngine:
         # ignores donation (with a warning), so only donate off-CPU
         self._donate = (1, 2) if jax.default_backend() != "cpu" else ()
         self.iters_ema = IterationEMA(alpha=ema_alpha)
+        if data_axis is not None and mesh is None:
+            raise ValueError("data_axis requires a mesh")
+        # the fine solves' heads layout: lanes over data_axis, sample dims
+        # over the denoiser's own axes.  Raises clearly on an unbound axis
+        # or a denoiser that shards the lane dim the engine owns.
+        self._heads_spec = P()
+        if mesh is not None and (data_axis is not None
+                                 or den.is_model_parallel):
+            self._heads_spec = denoiser_spec(data_axis, den, mesh=mesh)
         if data_axis is not None:
-            if mesh is None:
-                raise ValueError("data_axis requires a mesh")
-            microbatch_spec(data_axis, mesh=mesh)   # clear unbound-axis error
             d = mesh.shape[data_axis]
             if batch_size % d != 0:
                 raise ValueError(
@@ -1149,25 +1159,26 @@ class DiffusionSamplingEngine:
                                   t_model=sched.t_model.astype(self.dtype),
                                   kind=sched.kind)
         starts = jnp.arange(B, dtype=jnp.int32) * S
-        den, norm = self.denoiser, self.norm
+        norm = self.norm
         use_fused = self.use_fused
         accel = self.accel
+        # without a mesh both solves call the seam standalone.  Under a
+        # mesh every program body runs inside one replicated shard_map
+        # (``_spmd``): the coarse sweep takes the seam's inner form (a
+        # model-parallel denoiser slices, runs its shard_fn, gathers), and
+        # the fine solves, whose heads _make_fine slices once per solve,
+        # run shard_fn directly
+        den = self.denoiser
+        coarse_den, fine_den = (den, den) if self.mesh is None \
+            else (den.inner_eval(), den.shard_eval())
 
         def G(x, i0):
-            # coarse sweep + corrector run outside any shard_map: the
-            # seam's standalone call (a model-parallel denoiser self-wraps
-            # its shard_fn over the bound mesh; a plain one is just fn)
-            return solve(den, sched, solver, x, i0, 1, S)
+            return solve(coarse_den, sched, solver, x, i0, 1, S)
 
-        def fine_F(eval_fn):
-            # fine-solve factory: _make_fine picks the seam composition
-            # (standalone den for the vmap path, den.shard_eval() inside
-            # the shard_map whose specs come from denoiser_spec)
-            def F(x, i0):
-                return solve(eval_fn, sched, solver, x, i0, S, 1)
-            return F
+        def F(x, i0):
+            return solve(fine_den, sched, solver, x, i0, S, 1)
 
-        fine = self._make_fine(fine_F, starts, B)
+        fine = self._make_fine(F, starts, B)
 
         def init_body(x_init, x_tail, prev_coarse, new_mask):
             # coarse initialization sweep for the whole slot batch, with
@@ -1179,7 +1190,7 @@ class DiffusionSamplingEngine:
             return (jnp.where(m, tail0, x_tail),
                     jnp.where(m, tail0, prev_coarse))
 
-        init_fn = jax.jit(init_body, donate_argnums=self._donate)
+        init_fn = jax.jit(self._spmd(init_body), donate_argnums=self._donate)
 
         step_cache: Dict[int, Callable] = {}
         step_win_cache: Dict[int, Callable] = {}
@@ -1221,7 +1232,7 @@ class DiffusionSamplingEngine:
                     return new_tail, cur_all, delta, astate
 
                 donate = self._donate + (4,) if self._donate else ()
-                return jax.jit(step_accel, donate_argnums=donate)
+                return jax.jit(self._spmd(step_accel), donate_argnums=donate)
 
             def step_fn(x_init, x_tail, prev_coarse, active):
                 """One Parareal refinement over all K slots, truncated to
@@ -1243,7 +1254,7 @@ class DiffusionSamplingEngine:
                 delta = jnp.where(active, delta, jnp.inf)
                 return new_tail, cur_all, delta
 
-            return jax.jit(step_fn, donate_argnums=self._donate)
+            return jax.jit(self._spmd(step_fn), donate_argnums=self._donate)
 
         def make_step_windowed(minf: int):
             if accel.accelerates:
@@ -1286,7 +1297,7 @@ class DiffusionSamplingEngine:
                             jnp.concatenate([delta, br_g]), astate)
 
                 donate = self._donate + (5,) if self._donate else ()
-                return jax.jit(step_accel, donate_argnums=donate)
+                return jax.jit(self._spmd(step_accel), donate_argnums=donate)
 
             def step_fn(x_init, x_tail, prev_coarse, active, lo):
                 """One residual-window refinement over all K slots: the
@@ -1319,7 +1330,7 @@ class DiffusionSamplingEngine:
                         [jnp.zeros((minf,), br_g.dtype), br_g])
                 return new_tail, cur_all, jnp.concatenate([delta, br_g])
 
-            return jax.jit(step_fn, donate_argnums=self._donate)
+            return jax.jit(self._spmd(step_fn), donate_argnums=self._donate)
 
         def step_for(minf: int) -> Callable:
             if minf not in step_cache:
@@ -1338,28 +1349,35 @@ class DiffusionSamplingEngine:
         self._programs[key] = (init_fn, step_for, B, S)
         return self._programs[key]
 
-    def _make_fine(self, fine_F, starts, B: int):
-        """The fine-solve hook: vmapped in one program, or shard_mapped over
-        the block axis (``axis``), the slot batch (``data_axis``), the
-        denoiser's own model axes, or any combination.
+    def _spmd(self, body: Callable) -> Callable:
+        """``body`` as one program over the engine mesh: a shard_map with
+        every operand and result replicated, inside which the fine-solve
+        hook splits blocks, lanes and the denoiser's sample dims across
+        devices and all-gathers them.
+        Everything else — the coarse sweep, the corrector and residual,
+        mixing — runs on each device's full replica, so Pallas kernels
+        there are per-device calls (Mosaic kernels cannot be
+        auto-partitioned across a mesh).  Identity without a mesh."""
+        if self.mesh is None:
+            return body
+        return compat.shard_map(body, mesh=self.mesh, in_specs=P(),
+                                out_specs=P(), check_vma=False)
 
-        Block parallelism slices the local blocks by ``axis_index`` and
-        re-joins them with one tiled ``all_gather`` per iteration (the
-        :func:`repro.core.pipelined.srds_sharded_local` layout); slot-batch
-        parallelism needs no collectives at all — lanes are independent, so
-        ``shard_map`` just splits the K axis.  A model-parallel
-        :class:`~repro.core.denoiser.Denoiser` contributes its ``in_spec``
-        sample axes to the same specs via
-        :func:`repro.parallel.sharding.denoiser_spec`, and the body
-        evaluates its ``shard_eval()`` directly — no per-eval collectives
-        beyond the backbone's own.  That is the (time, data, model)
-        composition: one shard_map, zero driver-specific model code.
+    def _make_fine(self, F, starts, B: int):
+        """The fine-solve hook: ``F`` vmapped over the (suffix) block stack.
+
+        Under a mesh it runs inside :meth:`_spmd`'s replicated body: the
+        heads tensor is sliced once to the engine's heads spec — blocks
+        over ``axis`` (the :func:`repro.core.pipelined.srds_sharded_local`
+        layout), lanes over ``data_axis`` (lanes are independent), sample
+        dims over a model-parallel denoiser's own axes
+        (:func:`repro.parallel.sharding.denoiser_spec`) — every eval of the
+        solve is the denoiser's ``shard_eval``, and one tiled
+        ``all_gather`` per sharded dim re-joins the result.  That is the
+        (time, data, model) composition: one shard_map, zero
+        driver-specific model code.
         """
-        den = self.denoiser
-        if self.mesh is None or (self.axis is None and self.data_axis is None
-                                 and not den.is_model_parallel):
-            F = fine_F(den)   # standalone seam: self-wraps if model-parallel
-
+        if self.mesh is None:
             def fine(x_heads):
                 # truncated step programs pass the active suffix; recover
                 # the static offset from the stack length
@@ -1367,31 +1385,16 @@ class DiffusionSamplingEngine:
                 return jax.vmap(F)(x_heads, starts[f:] if f else starts)
             return fine
 
-        heads_spec = denoiser_spec(self.data_axis, den, mesh=self.mesh)
-        F = fine_F(den.shard_eval())   # specs already shard per in_spec
+        axis = self.axis
+        if axis is not None and B % self.mesh.shape[axis] != 0:
+            raise ValueError(
+                f"num_blocks={B} not divisible by axis size "
+                f"{self.mesh.shape[axis]}")
+        spec = P(axis, *tuple(self._heads_spec)[1:])
 
-        if self.axis is not None:
-            axis = self.axis
-            d_axis = self.mesh.shape[axis]
-            if B % d_axis != 0:
-                raise ValueError(
-                    f"num_blocks={B} not divisible by axis size {d_axis}")
-
-            def fine_local(x_heads):
-                d = compat.axis_size(axis)
-                me = jax.lax.axis_index(axis)
-                b_local = B // d
-                my = jax.lax.dynamic_slice_in_dim(x_heads, me * b_local,
-                                                  b_local)
-                my_starts = jax.lax.dynamic_slice_in_dim(starts, me * b_local,
-                                                         b_local)
-                y_local = jax.vmap(F)(my, my_starts)
-                return jax.lax.all_gather(y_local, axis, tiled=True)
-        else:
-            def fine_local(x_heads):
-                f = B - x_heads.shape[0]
-                return jax.vmap(F)(x_heads, starts[f:] if f else starts)
-
-        return compat.shard_map(fine_local, mesh=self.mesh,
-                                in_specs=heads_spec, out_specs=heads_spec,
-                                check_vma=False)
+        def fine(x_heads):
+            f = B - x_heads.shape[0]
+            st = slice_spec(starts[f:] if f else starts, P(axis))
+            y = jax.vmap(F)(slice_spec(x_heads, spec), st)
+            return gather_spec(y, spec)
+        return fine

@@ -6,6 +6,11 @@ import sys
 
 import pytest
 
+# tests run on the CPU, with the Pallas kernels interpreted.  Pinning the
+# platform before JAX is imported keeps every worker process off the TPU
+# library: only tests/test_tpu_compile.py loads it, to describe a chip.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 # tests/test_reprolint.py (and the CI lint leg) must collect and run on a
 # box with no JAX at all — the heavy imports are optional at conftest level
 # and every JAX-dependent test module fails loudly on its own import.
@@ -32,8 +37,13 @@ def pytest_configure(config):
 
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 900, env_extra=None):
-    """Run a python snippet with N fake devices; return CompletedProcess."""
+    """Run a python snippet with N fake CPU devices; return CompletedProcess.
+
+    The child is pinned to CPU: the fake devices are CPU devices, and a
+    child that reached for an accelerator would contend for a chip the
+    parent may already hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     if env_extra:

@@ -1,110 +1,52 @@
-"""Compat-substrate tests: both API branches (modern kwargs present vs
-absent) are exercised via monkeypatched stand-ins, and the resolved surface
-is checked against the really-installed JAX on a single-device mesh."""
+"""Compat-substrate tests: the wrappers forward the modern kwargs to the
+installed JAX (checked with monkeypatched stand-ins), and the resolved
+surface is checked against the really-installed JAX on a single-device
+mesh."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
 
 
 # --------------------------------------------------------------------------
-# stand-ins for the two historical shard_map surfaces
+# stand-ins for jax.shard_map / jax.make_mesh
 # --------------------------------------------------------------------------
 
-def _modern_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return {"api": "modern", "f": f, "mesh": mesh, "in_specs": in_specs,
+def _fake_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    return {"f": f, "mesh": mesh, "in_specs": in_specs,
             "out_specs": out_specs, "check": check_vma}
 
 
-def _legacy_shard_map(f, *, mesh, in_specs, out_specs, check_rep=True):
-    return {"api": "legacy", "f": f, "mesh": mesh, "in_specs": in_specs,
-            "out_specs": out_specs, "check": check_rep}
-
-
-@pytest.mark.parametrize("impl,api", [(_modern_shard_map, "modern"),
-                                      (_legacy_shard_map, "legacy")])
-def test_shard_map_translates_check_kwarg(monkeypatch, impl, api):
-    monkeypatch.setattr(compat, "_raw_shard_map", lambda: impl)
+def test_shard_map_translates_check_kwarg(monkeypatch):
+    monkeypatch.setattr(jax, "shard_map", _fake_shard_map)
     fn = lambda x: x
     out = compat.shard_map(fn, mesh="MESH", in_specs=P(), out_specs=P(),
                            check_vma=False)
-    assert out["api"] == api
-    # check_vma=False must reach the impl whichever kwarg it spells
+    # check_vma=False must reach the impl
     assert out["check"] is False
     assert out["f"] is fn and out["mesh"] == "MESH"
 
 
-@pytest.mark.parametrize("impl", [_modern_shard_map, _legacy_shard_map])
-def test_shard_map_default_check_left_alone(monkeypatch, impl):
-    monkeypatch.setattr(compat, "_raw_shard_map", lambda: impl)
+def test_shard_map_default_check_left_alone(monkeypatch):
+    monkeypatch.setattr(jax, "shard_map", _fake_shard_map)
     out = compat.shard_map(lambda x: x, mesh="M", in_specs=P(), out_specs=P())
     assert out["check"] is True   # impl default, untouched
 
 
-def test_shard_map_branches_identical(monkeypatch):
-    """The two branches must produce identical call contents."""
-    monkeypatch.setattr(compat, "_raw_shard_map", lambda: _modern_shard_map)
-    a = compat.shard_map(abs, mesh="M", in_specs=P("x"), out_specs=P(),
-                         check_vma=False)
-    monkeypatch.setattr(compat, "_raw_shard_map", lambda: _legacy_shard_map)
-    b = compat.shard_map(abs, mesh="M", in_specs=P("x"), out_specs=P(),
-                         check_vma=False)
-    a.pop("api"), b.pop("api")
-    assert a == b
-
-
-# --------------------------------------------------------------------------
-# stand-ins for the two historical make_mesh surfaces
-# --------------------------------------------------------------------------
-
-def _modern_make_mesh(axis_shapes, axis_names, *, devices=None,
-                      axis_types=None):
+def _fake_make_mesh(axis_shapes, axis_names, axis_types=None, *,
+                      devices=None):
     return {"shapes": tuple(axis_shapes), "names": tuple(axis_names),
             "devices": devices, "axis_types": axis_types}
 
 
-def _legacy_make_mesh(axis_shapes, axis_names, *, devices=None):
-    return {"shapes": tuple(axis_shapes), "names": tuple(axis_names),
-            "devices": devices, "axis_types": None}
-
-
 def test_make_mesh_modern_gets_auto_axis_types(monkeypatch):
-    monkeypatch.setattr(compat, "_raw_make_mesh", lambda: _modern_make_mesh)
+    monkeypatch.setattr(jax, "make_mesh", _fake_make_mesh)
     monkeypatch.setattr(compat, "axis_type_auto", lambda: "AUTO")
     out = compat.make_mesh((2, 4), ("data", "model"))
     assert out["axis_types"] == ("AUTO", "AUTO")
     assert out["shapes"] == (2, 4) and out["names"] == ("data", "model")
-
-
-def test_make_mesh_legacy_drops_axis_types(monkeypatch):
-    """A legacy make_mesh (no axis_types kwarg) must not be passed one —
-    even when explicitly requested — instead of raising TypeError."""
-    monkeypatch.setattr(compat, "_raw_make_mesh", lambda: _legacy_make_mesh)
-    out = compat.make_mesh((2, 4), ("data", "model"),
-                           axis_types=("whatever",) * 2)
-    assert out["axis_types"] is None
-    assert out["shapes"] == (2, 4) and out["names"] == ("data", "model")
-
-
-def test_make_mesh_branches_identical(monkeypatch):
-    """Modulo the axis_types extra, both branches see the same call."""
-    monkeypatch.setattr(compat, "axis_type_auto", lambda: None)
-    monkeypatch.setattr(compat, "_raw_make_mesh", lambda: _modern_make_mesh)
-    a = compat.make_mesh((4,), ("data",), devices="DEVS")
-    monkeypatch.setattr(compat, "_raw_make_mesh", lambda: _legacy_make_mesh)
-    b = compat.make_mesh((4,), ("data",), devices="DEVS")
-    assert a == b
-
-
-def test_make_mesh_no_impl_fallback(monkeypatch):
-    """Pre-make_mesh JAX: the compat layer builds a Mesh by hand."""
-    monkeypatch.setattr(compat, "_raw_make_mesh", lambda: None)
-    m = compat.make_mesh((1,), ("data",))
-    assert m.axis_names == ("data",)
-    assert m.devices.shape == (1,)
 
 
 # --------------------------------------------------------------------------
@@ -125,13 +67,6 @@ def test_shard_map_real_jax_executes():
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0) * 2.0)
 
 
-def test_replication_check_kwarg_detection():
-    assert compat._replication_check_kwarg(_modern_shard_map) == "check_vma"
-    assert compat._replication_check_kwarg(_legacy_shard_map) == "check_rep"
-    assert compat._replication_check_kwarg(
-        lambda f, mesh, in_specs, out_specs: None) is None
-
-
 def test_cost_analysis_normalizes_all_shapes():
     class FakeCompiled:
         def __init__(self, ret):
@@ -140,11 +75,9 @@ def test_cost_analysis_normalizes_all_shapes():
         def cost_analysis(self):
             return self._ret
 
-    # 0.4.x: one-element list of dicts; new JAX: dict; None: unsupported
-    assert compat.cost_analysis(FakeCompiled([{"flops": 7.0}])) == {"flops": 7.0}
+    # a dict, or None on backends without a cost model
     assert compat.cost_analysis(FakeCompiled({"flops": 7.0})) == {"flops": 7.0}
     assert compat.cost_analysis(FakeCompiled(None)) == {}
-    assert compat.cost_analysis(FakeCompiled([])) == {}
 
 
 def test_cost_analysis_real_jax():

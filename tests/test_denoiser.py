@@ -3,6 +3,7 @@ and the spec composition in parallel.sharding (single-device — the
 multi-device numerics live in test_distributed_srds.py)."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
@@ -111,6 +112,51 @@ def test_serving_engine_rejects_unbound_data_axis_and_meshless_mp():
                    out_spec=P(None, "model"), mesh_axes={"model": 1})
     with pytest.raises(ValueError, match="needs a mesh"):
         DiffusionSamplingEngine(den, (2, 2), num_steps=8, batch_size=1)
+
+
+def test_serving_engine_rejects_lane_sharding_denoiser():
+    # the engine owns the lane dim (data_axis): a denoiser whose in_spec
+    # shards it cannot be composed into the fine solves' heads spec
+    den = Denoiser(fn=_fn, shard_fn=_shard_fn, in_spec=P("model",),
+                   out_spec=P("model",), mesh_axes={"model": 1})
+    from repro.serve.diffusion import DiffusionSamplingEngine
+    with pytest.raises(ValueError, match="owns that dim via data_axis"):
+        DiffusionSamplingEngine(den, (2, 2), num_steps=8, batch_size=1,
+                                mesh=MESH3(), axis="time", data_axis="data")
+
+
+def _smooth(x, t):
+    return jnp.tanh(x) * (0.5 + 0.001 * t)
+
+
+def test_serving_engine_composes_model_parallel_denoiser_on_mesh():
+    """A model-parallel denoiser whose shard_fn needs its ``model`` axis
+    bound (a collective) runs in the engine's mesh programs — the coarse
+    sweep through ``inner_eval``, the fine solves through ``shard_eval`` —
+    and serves the samples of the plain function without a mesh, up to
+    float roundoff: the collective ends an XLA fusion, so the solver's
+    multiply-adds round apart in the last bits."""
+    from repro.serve.diffusion import DiffusionSamplingEngine, SampleRequest
+
+    def shard_fn(x, t):
+        return jax.lax.psum(_smooth(x, t), "model")   # size-1 axis: identity
+
+    den = Denoiser(fn=_smooth, shard_fn=shard_fn, in_spec=P(None, "model"),
+                   out_spec=P(None, "model"), mesh_axes={"model": 1})
+    reqs = [SampleRequest(seed=s, tol=1e-3) for s in range(3)]
+    out = []
+    for model, kw in ((den, dict(mesh=MESH3(), axis="time",
+                                 data_axis="data")),
+                      # the block axis runs FixedBudget: match it
+                      (_smooth, dict(truncate=False))):
+        eng = DiffusionSamplingEngine(model, (4, 2), num_steps=16,
+                                      batch_size=2, **kw)
+        rids = [eng.submit(r) for r in reqs]
+        got = eng.drain()
+        out.append([(got[i].sample, got[i].iterations) for i in rids])
+    for (a, ia), (b, ib) in zip(*out):
+        assert ia == ib
+        np.testing.assert_allclose(a, b, rtol=2e-6, atol=0)
 
 
 def test_sharded_driver_rejects_mesh_missing_model_axis():

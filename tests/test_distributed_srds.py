@@ -333,8 +333,8 @@ def test_dit_denoiser_composes_time_data_model_mesh():
     ``model``) matches the single-device driver within the documented
     shape-dependent-gemm carve-out, in all three drivers — ``srds_sample``
     (vmap-of-shard_map), the sharded driver (``inner_eval`` glue inside the
-    time/data shard_map), and the serving engine (``shard_eval`` under
-    ``denoiser_spec``)."""
+    time/data shard_map), and the serving engine (``inner_eval`` inside
+    its replicated program shard_map)."""
     code = r"""
 import dataclasses as dc
 import jax

@@ -4,6 +4,7 @@ fallback, malformed-table loud failure, and the divisor helpers that
 replaced ops._pick_chunk/_sample_tile_rows.  Pure-Python logic plus the
 committed tables — no kernel launches."""
 import json
+from pathlib import Path
 
 import jax.numpy as jnp
 import pytest
@@ -215,6 +216,29 @@ def test_gpu_runtime_env_knob_mapping_and_validation():
     jax.devices()
     with pytest.raises(RuntimeError, match="backend initialized"):
         lenv.configure_platform("cpu", gpu_preallocate=False)
+
+
+def test_compile_cache_dir_is_env_or_fixed_in_repo(monkeypatch):
+    import jax
+
+    from repro.launch import env as lenv
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        # set from outside: JAX reads the variable itself, nothing is set
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert lenv.configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        # unset: the same fixed directory inside the checkout, every call
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = lenv.configure_compile_cache()
+        assert first == lenv.configure_compile_cache() == \
+            jax.config.jax_compilation_cache_dir
+        root = Path(__file__).resolve().parents[1]
+        assert Path(first) == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
 
 
 # --------------------------------------------------------------------------
