@@ -15,6 +15,14 @@ The three samplers differ only in *where the fine solves run* (vmapped in
 one program, locally per shard with an all_gather, or wavefront-staggered)
 — that part is injected into :func:`run_parareal` as ``fine_fn`` — so the
 algorithm itself can no longer drift between implementations.
+
+Each device phase of a refinement runs under a ``jax.named_scope``, which
+lands in the ``op_name`` metadata of every HLO op it emits (and costs
+nothing at run time): ``srds.init`` (the coarse initialization sweep),
+``srds.fine`` (the fine solves; the serving engine scopes its own),
+``srds.coarse`` (each ``G`` of the sequential sweep) and ``srds.correct``
+(the predictor-corrector update and its residual).  A profile splits a
+refinement's device time by them.
 """
 from __future__ import annotations
 
@@ -362,7 +370,8 @@ def coarse_init_sweep(G, x_init: jnp.ndarray, starts: jnp.ndarray,
         g = G(x, i0)
         return g, g
 
-    _, x_tail = jax.lax.scan(body, x_init, starts, unroll=unroll)
+    with jax.named_scope("srds.init"):
+        _, x_tail = jax.lax.scan(body, x_init, starts, unroll=unroll)
     return x_tail
 
 
@@ -406,21 +415,24 @@ def corrector_sweep(G, x_init: jnp.ndarray, y: jnp.ndarray,
 
         def sweep_r(x_cur, inp):
             y_i, prev_i, old_i, i0 = inp[:4]
-            cur = G(x_cur, i0)
-            if use_fused:
-                x_next, r = kops.parareal_update_residual(
-                    y_i, cur, prev_i, old_i, batched=batched)
-            else:
-                x_next = y_i + cur - prev_i
-                d = (x_next - old_i).astype(jnp.float32)
-                r = jnp.sum(jnp.abs(d),
-                            axis=tuple(range(1, d.ndim)) if batched else None)
-            if frozen is not None:
-                fz_i = inp[4]
-                m = fz_i.reshape(fz_i.shape + (1,) * (x_next.ndim - fz_i.ndim))
-                x_next = jnp.where(m, old_i, x_next)
-                cur = jnp.where(m, prev_i, cur)
-                r = jnp.where(fz_i, jnp.zeros_like(r), r)
+            with jax.named_scope("srds.coarse"):
+                cur = G(x_cur, i0)
+            with jax.named_scope("srds.correct"):
+                if use_fused:
+                    x_next, r = kops.parareal_update_residual(
+                        y_i, cur, prev_i, old_i, batched=batched)
+                else:
+                    x_next = y_i + cur - prev_i
+                    d = (x_next - old_i).astype(jnp.float32)
+                    r = jnp.sum(jnp.abs(d), axis=tuple(range(1, d.ndim))
+                                if batched else None)
+                if frozen is not None:
+                    fz_i = inp[4]
+                    m = fz_i.reshape(fz_i.shape
+                                     + (1,) * (x_next.ndim - fz_i.ndim))
+                    x_next = jnp.where(m, old_i, x_next)
+                    cur = jnp.where(m, prev_i, cur)
+                    r = jnp.where(fz_i, jnp.zeros_like(r), r)
             return x_next, (x_next, cur, r)
 
         xs = (y, prev_coarse, residual_from, starts)
@@ -432,8 +444,10 @@ def corrector_sweep(G, x_init: jnp.ndarray, y: jnp.ndarray,
 
     def sweep(x_cur, inp):
         y_i, prev_i, i0 = inp
-        cur = G(x_cur, i0)
-        x_next = parareal_update(y_i, cur, prev_i, use_fused)
+        with jax.named_scope("srds.coarse"):
+            cur = G(x_cur, i0)
+        with jax.named_scope("srds.correct"):
+            x_next = parareal_update(y_i, cur, prev_i, use_fused)
         return x_next, (x_next, cur)
 
     _, (new_tail, cur_all) = jax.lax.scan(sweep, x_init,
@@ -511,8 +525,9 @@ def suffix_refinement(G, y, x_init: jnp.ndarray, x_tail: jnp.ndarray,
                 unroll=unroll, residual_from=old_sfx, batched=batched,
                 frozen=fz)
             # frozen blocks hold their old value -> their norm is 0
-            block_resid = blockwise_norm(new_sfx - old_sfx, norm,
-                                         batched=batched)
+            with jax.named_scope("srds.correct"):
+                block_resid = blockwise_norm(new_sfx - old_sfx, norm,
+                                             batched=batched)
         resid = block_resid[-1]
     elif fused_resid or block_resids:
         new_sfx, cur_sfx, r_all = corrector_sweep(
@@ -525,8 +540,9 @@ def suffix_refinement(G, y, x_init: jnp.ndarray, x_tail: jnp.ndarray,
             else:
                 resid = (r_all[-1] / float(n_per)).astype(jnp.float32)
         else:
-            block_resid = blockwise_norm(new_sfx - old_sfx, norm,
-                                         batched=batched)
+            with jax.named_scope("srds.correct"):
+                block_resid = blockwise_norm(new_sfx - old_sfx, norm,
+                                             batched=batched)
             resid = block_resid[-1]
     else:
         new_sfx, cur_sfx = corrector_sweep(G, x_carry, y, prev_sfx, st,
@@ -539,8 +555,9 @@ def suffix_refinement(G, y, x_init: jnp.ndarray, x_tail: jnp.ndarray,
     else:
         new_tail, cur_all = new_sfx, cur_sfx
     if resid is None:
-        resid = convergence_norm(new_tail[-1] - x_tail[-1], norm,
-                                 batched=batched)
+        with jax.named_scope("srds.correct"):
+            resid = convergence_norm(new_tail[-1] - x_tail[-1], norm,
+                                     batched=batched)
     if block_resids:
         return new_tail, cur_all, resid, block_resid
     return new_tail, cur_all, resid
@@ -756,7 +773,8 @@ def run_parareal(G, fine_fn: FineFn, x_init: jnp.ndarray,
         if f:
             heads = heads[f:]
         # ---- fine solves (Alg 1, lines 7-8) — sampler-specific ----
-        y = fine_fn(heads, c.p, c.y_prev)
+        with jax.named_scope("srds.fine"):
+            y = fine_fn(heads, c.p, c.y_prev)
         # ---- sequential coarse sweep + predictor-corrector (lines 9-12),
         # truncated to the suffix — the one shared implementation ----
         new_tail, cur_all, resid = suffix_refinement(

@@ -47,11 +47,21 @@ sleep, latency/SLO stamps read real seconds, and wall deadlines
 admission rejection and mid-flight eviction through
 ``engine.request_deadline``.  ``benchmarks/table10_wallclock.py`` is the
 wall-clock twin of ``table10_slo.py`` built on this loop.
+
+Tracing: ``run`` opens ``serve.submit`` (``requests``) around the submit
+loop, and each turn ``serve.admission`` around arrivals, the preemption
+round and slot filling, with ``waiting`` (the queue the round saw),
+``scanned`` (waiting entries examined by the round's eligibility scans,
+summed) and ``admitted``.  The engine's spans nest inside these or stand
+between them (``docs/serving.md``); an idle loop's sleep is the clock's
+``serve.wait``, inside no other span.
 """
 from __future__ import annotations
 
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.serve.diffusion import (DiffusionSamplingEngine, SampleRequest,
                                    SampleResponse)
@@ -100,11 +110,12 @@ class AsyncServeLoop:
         engine, policy = self.engine, self.policy
         engine.reset_metrics()
 
-        pending: List[Tuple[int, SampleRequest]] = \
-            [(engine.submit(r), r)
-             for r in sorted(trace, key=lambda r: r.arrival_time)]
-        submitted = [rid for rid, _ in pending]
-        engine.pull_queue()       # the loop owns admission, not drain()
+        with TraceAnnotation("serve.submit", requests=len(trace)):
+            pending: List[Tuple[int, SampleRequest]] = \
+                [(engine.submit(r), r)
+                 for r in sorted(trace, key=lambda r: r.arrival_time)]
+            submitted = [rid for rid, _ in pending]
+            engine.pull_queue()   # the loop owns admission, not drain()
         first_arrival = pending[0][1].arrival_time if pending else 0.0
         engine.advance_clock(first_arrival)
 
@@ -119,9 +130,12 @@ class AsyncServeLoop:
             while pending and pending[0][1].arrival_time <= now:
                 waiting.append(pending.pop(0))
 
-        while pending or waiting or engine.busy() or outstanding:
-            now = engine.clock
+        def admission(now: float) -> Dict[str, int]:
+            """One turn's admission round: arrivals, preemption, slot
+            filling.  Returns the counts its ``serve.admission`` span
+            records."""
             arrivals(now)
+            seen = len(waiting)
 
             # ---- preemption round (policy-driven; wall-deadline eviction
             # fires here, between refinements, even mid-pipeline: the
@@ -144,7 +158,9 @@ class AsyncServeLoop:
             # predict_completion per waiter) runs O(admissions) instead of
             # O(rounds x waiters) — on a wall clock that host time is real
             # and would otherwise sit on the pipelined critical path.
+            scanned = admitted = 0
             while True:
+                scanned += len(waiting)
                 admissible = [i for i, (rid, req) in enumerate(waiting)
                               if engine.free_slots(req) > 0]
                 if not admissible:
@@ -159,6 +175,13 @@ class AsyncServeLoop:
                     continue
                 engine.admit(rid, req)
                 running[rid] = req
+                admitted += 1
+            return {"waiting": seen, "scanned": scanned,
+                    "admitted": admitted}
+
+        while pending or waiting or engine.busy() or outstanding:
+            with TraceAnnotation("serve.admission") as span:
+                span.set_metadata(**admission(engine.clock))
 
             # ---- the overlap: dispatch the next refinement BEFORE
             # blocking on the previous one's residual fetch ----

@@ -32,6 +32,8 @@ from __future__ import annotations
 
 import time
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Clock", "VirtualClock", "MonotonicClock"]
 
 
@@ -100,7 +102,10 @@ class MonotonicClock(Clock):
     def wait_until(self, t: float) -> None:
         delay = t - self.now()
         if delay > 0:
-            time.sleep(delay)
+            # the loop idling for traffic, told apart in a profile from
+            # the host's own work (``serve.*`` spans it never nests in)
+            with TraceAnnotation("serve.wait", ms=1e3 * delay):
+                time.sleep(delay)
 
     def reset(self) -> None:
         self._epoch = time.monotonic()

@@ -123,9 +123,21 @@ sliced to it once and every eval runs ``shard_eval`` directly, while the
 coarse sweep evals in the ``inner_eval`` form — so time x data x model
 all compose on one 3D mesh (:func:`repro.launch.mesh.make_srds_mesh`)
 with zero engine-specific model code.
+
+Tracing: the host calls of the hot loop open ``jax.profiler``
+annotations, recorded only while a profile is being taken (about a
+microsecond each otherwise): ``serve.admit`` (``rid``, ``waited_ms``),
+``serve.dispatch`` (``frontier``: the window floor the step ran at),
+``serve.compile`` (the first call of a program variant, inside
+``serve.dispatch``), ``serve.resolve``
+(``completed``) and ``serve.fetch`` (every device-to-host transfer).
+Their arguments are host numbers only, never device values.  The
+programs' device phases carry the ``srds.*`` scopes of
+:mod:`repro.core.engine`.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Callable, Dict, List, Optional, Tuple
@@ -163,7 +175,8 @@ def _host_fetch(x) -> np.ndarray:
     state only — never the whole trajectory).  Tests monkeypatch this to
     count syncs and hold the one-sync-per-iteration contract.
     """
-    return np.asarray(jax.device_get(x))
+    with jax.profiler.TraceAnnotation("serve.fetch"):
+        return np.asarray(jax.device_get(x))
 
 
 class IterationEMA:
@@ -502,8 +515,10 @@ class _MicroBatch:
             # occupied lanes keep their refined trajectories)
             m = np.zeros((K,), bool)
             m[self.newly] = True
-            self.x_tail, self.prev_coarse = self.init_fn(
-                self.x_init, self.x_tail, self.prev_coarse, jnp.asarray(m))
+            with self._compile_span("init", 0):
+                self.x_tail, self.prev_coarse = self.init_fn(
+                    self.x_init, self.x_tail, self.prev_coarse,
+                    jnp.asarray(m))
             if self.astate is not None:
                 # a recycled slot's mixing history belongs to its previous
                 # tenant: zero it so old transients never mix into the
@@ -517,21 +532,26 @@ class _MicroBatch:
             self.newly = []
 
         amask = jnp.asarray(self.active)
-        if self.policy.needs_block_residuals:
+        args = (self.x_init, self.x_tail, self.prev_coarse, amask)
+        windowed = self.policy.needs_block_residuals
+        if windowed:
             # residual-window step: the compiled suffix starts at the
             # quantized window floor, blocks [minf, lo) freeze by masking,
             # and the (B,) group block residual rides the one fetch
             lo, minf = self._window_frontier()
+            step = self.step_for.windowed(minf)
+            args += (jnp.int32(lo),)
+        else:
+            minf = self._frontier() if self.engine.truncate else 0
+            lo = minf
+            step = self.step_for(minf)
+        with self._compile_span("window" if windowed else "step", minf):
             if self.astate is not None:
-                self.x_tail, self.prev_coarse, fetch, self.astate = \
-                    self.step_for.windowed(minf)(
-                        self.x_init, self.x_tail, self.prev_coarse, amask,
-                        jnp.int32(lo), self.astate)
+                self.x_tail, self.prev_coarse, fetch, self.astate = step(
+                    *args, self.astate)
             else:
-                self.x_tail, self.prev_coarse, fetch = \
-                    self.step_for.windowed(minf)(
-                        self.x_init, self.x_tail, self.prev_coarse, amask,
-                        jnp.int32(lo))
+                self.x_tail, self.prev_coarse, fetch = step(*args)
+        if windowed:
             # effective = the window schedule every active lane actually
             # executes; physical = the compiled suffix width times K
             per_lane = self.cost.refine_evals_window(lo)
@@ -539,18 +559,7 @@ class _MicroBatch:
                      for k, s in enumerate(self.slots)
                      if s is not None and self.active[k]]
             phys += K * self.cost.refine_evals_window(minf)
-            windowed = True
         else:
-            minf = self._frontier() if self.engine.truncate else 0
-            lo = minf
-            if self.astate is not None:
-                self.x_tail, self.prev_coarse, fetch, self.astate = \
-                    self.step_for(minf)(
-                        self.x_init, self.x_tail, self.prev_coarse, amask,
-                        self.astate)
-            else:
-                self.x_tail, self.prev_coarse, fetch = self.step_for(minf)(
-                    self.x_init, self.x_tail, self.prev_coarse, amask)
             # effective = per-lane ideal (each lane truncated at its OWN
             # frontier when the engine truncates); physical = what the
             # lockstep program actually ran (K lanes at the group frontier)
@@ -559,13 +568,24 @@ class _MicroBatch:
                      for k, s in enumerate(self.slots)
                      if s is not None and self.active[k]]
             phys += K * self._refine_evals_at(minf)
-            windowed = False
         self.inflight += 1
         # the snapshot reads the REBOUND (post-step) x_tail: a device-side
         # slice enqueued before the next dispatch donates the buffer away
         return _InFlight(batch=self, fetch=fetch, snap=self.x_tail[-1],
                          lanes=lanes, windowed=windowed, lo=lo, phys=phys,
                          init_eff=init_eff, epoch=self.window_epoch)
+
+    def _compile_span(self, program: str, frontier: int):
+        """A ``serve.compile`` span around the first call of a program
+        variant (``init``, or a ``step``/``window`` step at ``frontier``),
+        the call that traces and compiles it; no span for later calls."""
+        variant = (program, frontier)
+        if variant in self.step_for.called:
+            return contextlib.nullcontext()
+        self.step_for.called.add(variant)
+        return jax.profiler.TraceAnnotation(
+            "serve.compile", frontier=frontier,
+            windowed=int(program == "window"), init=int(program == "init"))
 
     @hot_loop
     def resolve(self, tok: _InFlight):
@@ -1001,8 +1021,11 @@ class DiffusionSamplingEngine:
         must exist — check ``free_slots`` first).  Work on a request cannot
         start before it arrives, so the clock catches up to its
         ``arrival_time`` (keeps ``drain()`` latencies non-negative)."""
-        self.advance_clock(req.arrival_time)
-        self._batch_for(req).admit(rid, req)
+        with jax.profiler.TraceAnnotation(
+                "serve.admit", rid=rid,
+                waited_ms=1e3 * (self.clock - req.arrival_time)):
+            self.advance_clock(req.arrival_time)
+            self._batch_for(req).admit(rid, req)
 
     def busy(self) -> bool:
         return any(b.busy() for b in self._batches.values())
@@ -1033,7 +1056,10 @@ class DiffusionSamplingEngine:
             b = batches[(self._rr + off) % len(batches)]
             if b.busy() and b.inflight < max_inflight:
                 self._rr = (self._rr + off + 1) % len(batches)
-                return b.dispatch()
+                with jax.profiler.TraceAnnotation("serve.dispatch") as span:
+                    tok = b.dispatch()
+                    span.set_metadata(frontier=tok.lo)
+                return tok
         return None
 
     @hot_loop
@@ -1043,12 +1069,14 @@ class DiffusionSamplingEngine:
         (that refinement's ONE host sync), account effective/physical
         evals, charge the clock its physical cost, and finalize
         completions."""
-        completed, eff, phys = tok.batch.resolve(tok)
-        self.effective_evals += eff
-        self.physical_evals += phys
-        self._clock.charge(phys * self.sec_per_eval)
-        return [(rid, self._finalize(rid, req, resp))
-                for rid, req, resp in completed]
+        with jax.profiler.TraceAnnotation("serve.resolve") as span:
+            completed, eff, phys = tok.batch.resolve(tok)
+            self.effective_evals += eff
+            self.physical_evals += phys
+            self._clock.charge(phys * self.sec_per_eval)
+            span.set_metadata(completed=len(completed))
+            return [(rid, self._finalize(rid, req, resp))
+                    for rid, req, resp in completed]
 
     def evict(self, rid: int) -> SampleResponse:
         """Preempt a running request (scheduler policy decision); its
@@ -1344,6 +1372,9 @@ class DiffusionSamplingEngine:
 
         step_for.cache = step_cache     # introspectable: compiled variants
         step_for.windowed = step_windowed
+        # (program, frontier) variants called at least once: the first
+        # call traces and compiles (``_MicroBatch._compile_span``)
+        step_for.called = set()
         step_windowed.cache = step_win_cache
 
         self._programs[key] = (init_fn, step_for, B, S)
@@ -1382,7 +1413,8 @@ class DiffusionSamplingEngine:
                 # truncated step programs pass the active suffix; recover
                 # the static offset from the stack length
                 f = B - x_heads.shape[0]
-                return jax.vmap(F)(x_heads, starts[f:] if f else starts)
+                with jax.named_scope("srds.fine"):
+                    return jax.vmap(F)(x_heads, starts[f:] if f else starts)
             return fine
 
         axis = self.axis
@@ -1394,7 +1426,8 @@ class DiffusionSamplingEngine:
 
         def fine(x_heads):
             f = B - x_heads.shape[0]
-            st = slice_spec(starts[f:] if f else starts, P(axis))
-            y = jax.vmap(F)(slice_spec(x_heads, spec), st)
-            return gather_spec(y, spec)
+            with jax.named_scope("srds.fine"):
+                st = slice_spec(starts[f:] if f else starts, P(axis))
+                y = jax.vmap(F)(slice_spec(x_heads, spec), st)
+                return gather_spec(y, spec)
         return fine
