@@ -10,11 +10,15 @@ TPU (Mosaic) family — ``_fwd_kernel`` / ``_dq_kernel`` / ``_dkv_kernel``:
     the *innermost sequential grid dimension* (TPU grids iterate the last
     axis sequentially per core — the idiomatic replacement for a CUDA
     thread-block loop over KV tiles);
-  * tiles default to (128, 128): the MXU systolic array is 128x128, and the
-    lane dimension (head_dim) should be a multiple of 128 for full MXU
-    utilization — the ops wrapper pads head_dim when needed;
+  * tiles default to (128, 128): the MXU systolic array is 128x128; a
+    head_dim below 128 runs at a fraction of the lanes (nothing pads it);
   * causal and sliding-window masking skip fully-masked KV tiles with
-    ``pl.when`` (no MXU work issued for skipped tiles).
+    ``pl.when`` (no MXU work issued for skipped tiles);
+  * where the whole sequence fits one tile (``sq <= block_q`` and
+    ``sk <= block_k``, a DiT's 64 tokens), ``_fwd_kernel_batched`` takes
+    ``block_b`` (sample, head) rows a grid step with a single-pass softmax
+    and no scratch: each grid step costs a fixed ~0.5 µs on a v5e, far
+    more than one 64x64 head's math, so one head a step is all overhead.
 
 GPU (Triton) family — ``_fwd_kernel_gpu`` / ``_dq_kernel_gpu`` /
 ``_dkv_kernel_gpu``:
@@ -35,10 +39,13 @@ Both families are exercised in ``interpret=True`` mode on CPU (the parity
 suite); tile sizes come from :mod:`repro.kernels.tuning`.
 
 GQA is handled in the BlockSpec index_map (kv head = q head // group), so
-grouped KV is never materialized/repeated in HBM — both families.
+grouped KV is never materialized/repeated in HBM — both families (the
+batched forward repeats a block's kv heads in VMEM).
 
-Forward saves the per-row logsumexp; backward recomputes probabilities
-tile-by-tile (two kernels: dQ over KV tiles; dK/dV over Q tiles).
+Forward saves the per-row logsumexp where asked (``with_lse``, the
+custom-vjp forward); the inference forward of the batched path writes
+none.  Backward recomputes probabilities tile-by-tile (two kernels: dQ
+over KV tiles; dK/dV over Q tiles).
 """
 from __future__ import annotations
 
@@ -131,6 +138,66 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
         l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows -> 0
         o_ref[0] = (acc[...] / l_safe).astype(o_ref.dtype)
         lse_ref[0] = m_sc[...] + jnp.log(l_safe)
+
+
+def _fwd_kernel_batched(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale, causal,
+                        window, sq, sk, group):
+    """One grid step over a block of (sample, head) rows whose whole
+    sequence fits one tile: the softmax in a single pass, so no scratch
+    and no init/finalize.  Same f32 arithmetic, mask and fully-masked-row
+    guard as ``_fwd_kernel`` on one tile."""
+    q = q_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    if group > 1:  # the block's q rows of one kv head are contiguous
+        k = jnp.repeat(k, group, axis=0)
+        v = jnp.repeat(v, group, axis=0)
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * scale
+    keep = _mask(sq, sk, 0, 0, sq, sk, causal, window)
+    s = jnp.where(keep, s, NEG_INF)
+    m = jnp.max(s, axis=2, keepdims=True)
+    # guard fully-masked rows: m == NEG_INF would give exp(0) == 1
+    p = jnp.where(keep, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=2, keepdims=True)
+    acc = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                              preferred_element_type=jnp.float32)
+    l_safe = jnp.where(l == 0.0, 1.0, l)              # fully-masked rows -> 0
+    o_ref[...] = (acc / l_safe).astype(o_ref.dtype)
+    if lse_ref:
+        lse_ref[0][...] = m + jnp.log(l_safe)
+
+
+def _flash_fwd_batched(q, k, v, *, causal, window, scale, block_b, with_lse,
+                       interpret):
+    bh, sq, d = q.shape
+    bkv, sk, _ = k.shape
+    group = bh // bkv
+    if bh % block_b or block_b % group:
+        raise ValueError(f"block_b={block_b} must divide bh={bh} and be a "
+                         f"multiple of the GQA group {group}")
+    kernel = functools.partial(_fwd_kernel_batched, scale=scale,
+                               causal=causal, window=window, sq=sq, sk=sk,
+                               group=group)
+    out_specs = [pl.BlockSpec((block_b, sq, d), lambda i: (i, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bh, sq, d), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((block_b, sq, 1), lambda i: (i, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32))
+    kv_spec = pl.BlockSpec((block_b // group, sk, d), lambda i: (i, 0, 0))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(bh // block_b,),
+        in_specs=[pl.BlockSpec((block_b, sq, d), lambda i: (i, 0, 0)),
+                  kv_spec, kv_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="srds_flash_fwd",
+    )(q, k, v)
+    return outs[0], (outs[1][..., 0] if with_lse else None)
 
 
 # --------------------------------------------------------------------------
@@ -396,15 +463,19 @@ def _gpu_params(num_warps, num_stages):
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
-                        block_q=128, block_k=128, num_warps=None,
-                        num_stages=None, plat="tpu", interpret=False):
+                        block_q=128, block_k=128, block_b=1, num_warps=None,
+                        num_stages=None, plat="tpu", with_lse=True,
+                        interpret=False):
     """q: (BH, Sq, D) already flattened over batch*q_heads; k/v: (BKV, Sk, D).
 
     ``group = BH // BKV`` kv-sharing factor (GQA) resolved via index_map.
     ``plat`` picks the kernel family ("tpu" grid-carried scratch vs "gpu"
     in-kernel loop; see module docstring) — resolved by the ops layer from
     the backend, orthogonal to ``interpret``.  ``num_warps``/``num_stages``
-    only apply to the Triton family.  Returns (o (BH, Sq, D), lse (BH, Sq)).
+    only apply to the Triton family.  ``block_b`` > 1 takes that many
+    (sample, head) rows a grid step where the sequence fits one tile (TPU
+    family); it must divide BH and be a multiple of ``group``.  Returns
+    (o (BH, Sq, D), lse (BH, Sq)); lse is None when ``with_lse`` is False.
     """
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
@@ -413,11 +484,16 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
     bq = min(block_q, sq)
     bk = min(block_k, sk)
     if plat == "gpu":
-        return _flash_fwd_gpu(q, k, v, causal=causal, window=window,
-                              scale=scale, bq=bq, bk=bk,
-                              compiler_params=_gpu_params(num_warps,
-                                                          num_stages),
-                              interpret=interpret)
+        o, lse = _flash_fwd_gpu(q, k, v, causal=causal, window=window,
+                                scale=scale, bq=bq, bk=bk,
+                                compiler_params=_gpu_params(num_warps,
+                                                            num_stages),
+                                interpret=interpret)
+        return o, (lse if with_lse else None)
+    if block_b > 1 and bq == sq and bk == sk:
+        return _flash_fwd_batched(q, k, v, causal=causal, window=window,
+                                  scale=scale, block_b=block_b,
+                                  with_lse=with_lse, interpret=interpret)
     grid = (bh, pl.cdiv(sq, bq), pl.cdiv(sk, bk))
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -450,7 +526,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
         interpret=interpret,
         name="srds_flash_fwd",
     )(q, k, v)
-    return o, lse[..., 0]
+    return o, (lse[..., 0] if with_lse else None)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,

@@ -109,27 +109,31 @@ def fused_default() -> bool:
 # Flash attention (custom_vjp; Pallas fwd + Pallas bwd)
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, causal, window, scale, block_q, block_k, num_warps,
-           num_stages, plat):
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, causal, window, scale, block_q, block_k, block_b,
+           num_warps, num_stages, plat):
+    # the primal is the inference forward: no logsumexp to write
     o, _ = flash_attention_fwd(q, k, v, causal=causal, window=window,
                                scale=scale, block_q=block_q, block_k=block_k,
-                               num_warps=num_warps, num_stages=num_stages,
-                               plat=plat, interpret=_interpret())
+                               block_b=block_b, num_warps=num_warps,
+                               num_stages=num_stages, plat=plat,
+                               with_lse=False, interpret=_interpret())
     return o
 
 
-def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k, num_warps,
-               num_stages, plat):
+def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k, block_b,
+               num_warps, num_stages, plat):
     o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
                                  scale=scale, block_q=block_q, block_k=block_k,
-                                 num_warps=num_warps, num_stages=num_stages,
-                                 plat=plat, interpret=_interpret())
+                                 block_b=block_b, num_warps=num_warps,
+                                 num_stages=num_stages, plat=plat,
+                                 interpret=_interpret())
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, window, scale, block_q, block_k, num_warps, num_stages,
-               plat, res, do):
+def _flash_bwd(causal, window, scale, block_q, block_k, block_b, num_warps,
+               num_stages, plat, res, do):
     q, k, v, o, lse = res
     dq, dk_g, dv_g = flash_attention_bwd(
         q, k, v, o, lse, do, causal=causal, window=window, scale=scale,
@@ -158,9 +162,11 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     Block sizes resolve through the tuning seam (``tuner`` or the process
     default); explicit ``block_q``/``block_k``/``num_warps``/``num_stages``
-    act as overrides.  ``plat`` pins the kernel family (tests exercise the
-    Triton-structured kernels on CPU with ``plat="gpu"``); default follows
-    the backend.
+    act as overrides.  The resolved ``block_b`` caps the (sample, head)
+    rows one forward grid step takes; it is cut to the largest multiple
+    of the GQA group that divides ``B * Hq`` (:func:`tuning.pick_block_b`).
+    ``plat`` pins the kernel family (tests exercise the Triton-structured
+    kernels on CPU with ``plat="gpu"``); default follows the backend.
     """
     if use_kernel is None:
         use_kernel = not FORCE_REF
@@ -175,8 +181,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     qf = q.reshape(b * hq, sq, d)
     kf = k.reshape(b * hkv, sk, d)
     vf = v.reshape(b * hkv, sk, d)
+    block_b = tuning.pick_block_b(b * hq, hq // hkv, cfg.params["block_b"])
     o = _flash(qf, kf, vf, causal, window, scale,
-               cfg.params["block_q"], cfg.params["block_k"],
+               cfg.params["block_q"], cfg.params["block_k"], block_b,
                cfg.params.get("num_warps"), cfg.params.get("num_stages"),
                plat if plat is not None else _plat())
     return o.reshape(b, hq, sq, d)

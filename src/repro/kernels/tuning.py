@@ -29,7 +29,14 @@ kernel     parameters                      tuning shape (bucket basis)
 elementwise ``tile_rows``                  operand shape -> (total size,)
 flash       ``block_q``, ``block_k`` (+    ``(sq, sk, head_dim)``
             ``num_warps``, ``num_stages``
-            on the Triton lowering)
+            on the Triton lowering);
+            ``block_b``, derived: the
+            (sample, head) rows a forward
+            grid step takes — 1 unless
+            ``sq <= block_q`` and ``sk <=
+            block_k``, else as many as fit
+            ``FLASH_STEP_VMEM_BYTES``
+            (:func:`flash_block_b`)
 rwkv6       ``chunk_target`` (TPU chunked  ``(t, dk)``
             grid; the GPU kernel streams
             timesteps and ignores it)
@@ -54,6 +61,7 @@ __all__ = [
     "KernelConfig", "KernelTuner", "TuningTableError", "TABLE_SCHEMA_VERSION",
     "TABLE_DIR", "KERNELS", "bucket_for", "next_pow2", "get_tuner",
     "set_tuner", "resolve", "pick_chunk", "sample_tile_rows",
+    "flash_block_b", "pick_block_b", "FLASH_STEP_VMEM_BYTES",
     "validate_table",
 ]
 
@@ -87,6 +95,43 @@ _HEURISTICS: Dict[str, Dict[Optional[str], Dict[str, int]]] = {
         None: {"chunk_target": 32},
     },
 }
+
+
+# VMEM one grid step of the batched (one-tile) flash forward may plan for,
+# well inside v5e's 16 MiB default scoped VMEM.  It gives 46 rows at
+# (64, 64, 64), so 24 of a DiT call's 48: on a v5e the fastest of 12, 16,
+# 24 and 48 rows a step, all within 5% of each other.
+FLASH_STEP_VMEM_BYTES = 8 << 20
+
+
+def flash_block_b(shape: Optional[Sequence[int]], block_q: int,
+                  block_k: int) -> int:
+    """The ``block_b`` heuristic: how many (sample, head) rows one flash
+    forward grid step may take at tuning shape ``(sq, sk, head_dim)``.
+
+    1 whenever the sequence spans more than one tile (the per-head launch
+    stays).  On one tile a grid step costs a fixed ~0.5 µs on a v5e while
+    a 64x64 head's math takes nanoseconds, so as many rows as fit
+    :data:`FLASH_STEP_VMEM_BYTES` at 4-byte words a row: 4·(sq+sk)·d for
+    the double-buffered q, o, k and v blocks and 3·sq·sk for the scores,
+    the probabilities and a temporary."""
+    if shape is None:
+        return 1
+    sq, sk, d = (int(x) for x in shape)
+    if sq > block_q or sk > block_k:
+        return 1
+    per_row = 4 * (4 * (sq + sk) * d + 3 * sq * sk)
+    return max(1, FLASH_STEP_VMEM_BYTES // per_row)
+
+
+def pick_block_b(rows: int, group: int, cap: int) -> int:
+    """Rows a batched flash step takes: the largest multiple of the GQA
+    ``group`` that divides ``rows`` (= batch * q heads) and is at most
+    ``cap`` (the resolved ``block_b``); 1 (the per-head launch) when
+    ``cap`` is below one group."""
+    if cap < max(group, 2):
+        return 1
+    return group * _largest_divisor(rows // group, cap // group)
 
 
 class TuningTableError(ValueError):
@@ -286,6 +331,9 @@ class KernelTuner:
         if pinned:
             params.update(pinned)
             source = "override"
+        if kernel == "flash" and "block_b" not in params:
+            params["block_b"] = flash_block_b(shape, params["block_q"],
+                                              params["block_k"])
         return KernelConfig(kernel=kernel, params=params, source=source,
                             key=(backend, kernel, dt, bucket))
 

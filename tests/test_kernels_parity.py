@@ -180,23 +180,64 @@ FLASH_CASES = [
 ]
 
 
+# The same fields, then where the sequence fits one tile at the default
+# tiles (the batched forward, many (sample, head) rows a grid step):
+# (leading vmap axis or None, block_b override or None, window)
+BATCHED_FLASH_CASES = [
+    (4, 12, 12, 64, 64, 64, False, None, None, None),  # served DiT eval
+    (4, 12, 12, 64, 64, 64, False, 5, None, None),     # fine solves: 5 blocks
+    (2, 4, 4, 40, 40, 32, True, None, None, None),     # causal, one tile
+    (1, 4, 4, 48, 48, 16, True, None, None, 8),        # causal + window
+    (2, 8, 2, 48, 48, 24, True, None, None, None),     # GQA 4x, one tile
+    (1, 6, 6, 16, 16, 16, False, None, 4, None),       # block_b 4 ∤ bh 6
+    (1, 8, 2, 16, 16, 16, True, None, 6, None),        # block_b 6, group 4
+]
+
+
+def _flash_case_id(c):
+    cid = f"B{c[0]}H{c[1]}-{c[2]}S{c[3]}x{c[4]}D{c[5]}c{int(c[6])}"
+    if len(c) == 7:
+        return cid
+    blocks, block_b, window = c[7:]
+    return cid + "-onetile" + (f"-vmap{blocks}" if blocks else "") + (
+        f"-bb{block_b}" if block_b else "") + (f"-w{window}" if window else "")
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize(
-    "case", FLASH_CASES,
-    ids=lambda c: f"B{c[0]}H{c[1]}-{c[2]}S{c[3]}x{c[4]}D{c[5]}c{int(c[6])}")
+@pytest.mark.parametrize("case", FLASH_CASES + BATCHED_FLASH_CASES,
+                         ids=_flash_case_id)
 def test_flash_attention_interpret_parity(case, dtype):
     """Flash kernel (interpret mode on CPU) vs the jnp oracle — the parity
     matrix behind the sharded DiT denoiser's attention path, which feeds
     local queries and all-gathered K/V through ``ops.attention`` with
-    ``use_kernel=True``.  Runs everywhere (no hypothesis dependency)."""
-    b, hq, hkv, sq, sk, d, causal = case
+    ``use_kernel=True``; the one-tile cases take the batched forward
+    (the served DiT shape, plain and vmapped over the fine solves'
+    blocks).  Runs everywhere (no hypothesis dependency)."""
+    b, hq, hkv, sq, sk, d, causal = case[:7]
+    blocks, block_b, window = case[7:] or (None, None, None)
+    if len(case) == 7:
+        tuner = TUNER32
+    elif block_b:
+        tuner = tuning.KernelTuner(overrides={"flash": {"block_b": block_b}})
+    else:
+        tuner = tuning.KernelTuner(table_dir="/nonexistent")
+    lead = (blocks, b) if blocks else (b,)
     dt = jnp.dtype(dtype)
-    q = jax.random.normal(KEYS[0], (b, hq, sq, d), dt)
-    k = jax.random.normal(KEYS[1], (b, hkv, sk, d), dt)
-    v = jax.random.normal(KEYS[2], (b, hkv, sk, d), dt)
-    out = ops.attention(q, k, v, causal=causal, tuner=TUNER32,
-                        use_kernel=True)
-    exp = ref.attention(q, k, v, causal=causal)
+    q = jax.random.normal(KEYS[0], (*lead, hq, sq, d), dt)
+    k = jax.random.normal(KEYS[1], (*lead, hkv, sk, d), dt)
+    v = jax.random.normal(KEYS[2], (*lead, hkv, sk, d), dt)
+
+    def attn(q, k, v):
+        return ops.attention(q, k, v, causal=causal, window=window,
+                             tuner=tuner, use_kernel=True)
+
+    def oracle(q, k, v):
+        return ref.attention(q, k, v, causal=causal, window=window)
+
+    if blocks:
+        attn, oracle = jax.vmap(attn), jax.vmap(oracle)
+    out = attn(q, k, v)
+    exp = oracle(q, k, v)
     assert out.shape == exp.shape and out.dtype == dt
     tol = 2e-2 if dtype == "bfloat16" else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -336,10 +377,14 @@ def test_elementwise_tuned_config_parity(dtype, cfgname):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("cfgname", ["default", "table", "override"])
+@pytest.mark.parametrize("cfgname", ["default", "table", "override",
+                                     "block_b"])
 def test_flash_tuned_config_parity(dtype, cfgname):
     """ops.attention under all three tuner resolution tiers on a
-    non-tile-multiple GQA case (boundary buckets)."""
+    non-tile-multiple GQA case (boundary buckets); ``block_b`` overrides
+    the batched forward's rows a step with a cap that divides neither
+    B*Hq=4 nor lands on the GQA group of 2.  The default tier batches
+    (sample, head) rows only where the sequence fits one tile."""
     b, hq, hkv, sq, sk, d, causal = 1, 4, 2, 33, 49, 16, True
     dt = jnp.dtype(dtype)
     q = jax.random.normal(KEYS[0], (b, hq, sq, d), dt)
@@ -352,11 +397,24 @@ def test_flash_tuned_config_parity(dtype, cfgname):
         tuner = _table_for("flash", dt, (sq, sk, d),
                            {"block_q": 16, "block_k": 8})
         want_src = "table"
-    else:
+    elif cfgname == "override":
         tuner = TUNER32
         want_src = "override"
-    assert tuner.resolve("flash", backend="cpu", dtype=dt,
-                         shape=(sq, sk, d)).source == want_src
+    else:
+        tuner = tuning.KernelTuner(overrides={"flash": {"block_b": 3}})
+        want_src = "override"
+        assert tuning.pick_block_b(b * hq, hq // hkv, 3) == 2
+    cfg = tuner.resolve("flash", backend="cpu", dtype=dt, shape=(sq, sk, d))
+    assert cfg.source == want_src
+    if cfgname == "default":
+        assert cfg.params["block_b"] > 1
+        committed = tuning.KernelTuner()
+        for backend in ("tpu", "cpu"):
+            def block_b(shape):
+                return committed.resolve("flash", backend=backend, dtype=dt,
+                                         shape=shape).params["block_b"]
+            assert block_b((64, 64, 64)) > 1
+            assert block_b((2048, 2048, 128)) == 1
     out = ops.attention(q, k, v, causal=causal, tuner=tuner,
                         use_kernel=True)
     exp = ref.attention(q, k, v, causal=causal)
