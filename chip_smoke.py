@@ -330,7 +330,7 @@ def time_parallel(model_fn, reqs, devices, *, shape=SHAPE,
     (batch,) = eng._batches.values()
     held = {d.id for d in batch.x_tail.sharding.device_set}
     step = batch.step_for(0).lower(
-        batch.x_init, batch.x_tail, batch.prev_coarse,
+        eng.params, batch.x_init, batch.x_tail, batch.prev_coarse,
         jnp.asarray(batch.active)).compile()
     groups = collective_group_sizes(step.as_text())
     res.update(trajectory_devices=sorted(held), collectives=groups)

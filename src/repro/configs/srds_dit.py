@@ -41,10 +41,9 @@ def dit_denoiser(arch, params, *, use_kernel=None, shard_axis=None,
 
     ``arch`` is a registered config name (``srds-dit-cifar`` /
     ``srds-dit-lsun`` / ``srds-dit-sd2``) or an :class:`ArchConfig`.
-    Without ``shard_axis`` this is the plain ``model_fn(x, t)`` every
-    sampler already consumes (adapted on entry via
-    :func:`repro.core.denoiser.as_denoiser`); with it, the returned
-    :class:`repro.core.denoiser.Denoiser` patch-shards the backbone over
+    The returned :class:`repro.core.denoiser.Denoiser` carries
+    ``params``, so the serving engine passes them to its programs as an
+    argument.  With ``shard_axis`` it patch-shards the backbone over
     that mesh axis — typically ``"model"`` on the (time, data, model) mesh
     from :func:`repro.launch.mesh.make_srds_mesh` — and every driver runs
     a genuinely model-parallel fine solve with no driver-side changes.

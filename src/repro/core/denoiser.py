@@ -11,14 +11,23 @@ callable that *also* carries its parallelism contract:
   over a ``model`` axis: ``P(None, "model")``);
 * ``mesh_axes`` — the axes the backbone requires of whatever mesh it runs
   under, as ``{axis_name: min_size}``;
-* ``fn`` — the single-device global math, the bit-exactness reference;
-* ``shard_fn`` — the per-shard body: takes/returns the ``in_spec`` /
-  ``out_spec`` shard and may use collectives over ``mesh_axes`` names.
+* ``params`` — the weights, a pytree (``()`` for a denoiser without);
+* ``apply(params, x, t)`` — the single-device global math, the
+  bit-exactness reference, pure in the weights;
+* ``shard_apply(params, x, t)`` — the per-shard body: takes/returns the
+  ``in_spec`` / ``out_spec`` shard and may use collectives over
+  ``mesh_axes`` names.
+
+``fn`` and ``shard_fn`` are ``apply`` and ``shard_apply`` closed over
+``params``.  A program that takes the weights as an argument (the serving
+engine's) evaluates ``den.with_params(traced)`` instead, so the weights
+never become constants of its HLO.
 
 Plain ``model_fn(x, t)`` callables adapt losslessly via
-:func:`as_denoiser` (replicated specs, no mesh requirement), so every
-existing call path is unchanged.  A model-parallel denoiser composes with
-the drivers' time/data parallelism in three ways, all driver-agnostic:
+:func:`as_denoiser` (no weights, replicated specs, no mesh requirement),
+so every existing call path is unchanged.  A model-parallel denoiser
+composes with the drivers' time/data parallelism in three ways, all
+driver-agnostic:
 
 1. **standalone** (``den(x, t)``): self-wraps ``shard_fn`` in a
    ``shard_map`` over the denoiser's bound ``mesh`` — what ``srds_sample``
@@ -37,7 +46,8 @@ the drivers' time/data parallelism in three ways, all driver-agnostic:
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Mapping, Optional
+import functools
+from typing import Any, Callable, Mapping, Optional
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -83,23 +93,60 @@ def gather_spec(y, spec):
 
 
 @dataclasses.dataclass(frozen=True)
-class Denoiser:
-    """A model-eval callable carrying its sharding contract (see module
-    docstring).  ``Denoiser(fn=f)`` with the defaults is exactly ``f`` —
-    replicated specs, no mesh requirement, zero overhead."""
+class _Closure:
+    """The ``(params, x, t)`` form of an ``(x, t)`` closure: it carries
+    its own weights, or none, and ignores ``params``."""
 
-    fn: Callable                        # global (x, t) -> eps, the reference
-    shard_fn: Optional[Callable] = None  # per-shard body (None = fn)
+    fn: Callable
+
+    def __call__(self, params, x, t):
+        return self.fn(x, t)
+
+
+def _close(apply: Optional[Callable], params) -> Optional[Callable]:
+    """``apply`` closed over ``params``: an ``(x, t)`` callable."""
+    if apply is None:
+        return None
+    if isinstance(apply, _Closure):
+        return apply.fn
+    return functools.partial(apply, params)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Denoiser:
+    """A model-eval callable carrying its weights and its sharding
+    contract (see module docstring).  ``as_denoiser(f)`` of a plain
+    ``f(x, t)`` is exactly ``f`` — no weights, replicated specs, no mesh
+    requirement, zero overhead."""
+
+    apply: Callable                          # (params, x, t) -> eps, global
+    shard_apply: Optional[Callable] = None   # per-shard body (None = apply)
+    params: Any = ()                         # the weights, a pytree
     in_spec: P = P()
     out_spec: P = P()
     mesh_axes: Mapping[str, int] = dataclasses.field(default_factory=dict)
-    mesh: Optional[Mesh] = None          # bound mesh for standalone calls
+    mesh: Optional[Mesh] = None              # bound mesh for standalone calls
 
     def __post_init__(self):
-        if self.mesh_axes and self.shard_fn is None:
+        if self.mesh_axes and self.shard_apply is None:
             raise ValueError("a Denoiser with mesh_axes needs a shard_fn")
         if self.mesh is not None:
             self.check_mesh(self.mesh)
+
+    @property
+    def fn(self) -> Callable:
+        """The global ``(x, t)`` eval, the reference."""
+        return _close(self.apply, self.params)
+
+    @property
+    def shard_fn(self) -> Optional[Callable]:
+        """The per-shard ``(x, t)`` body (``None`` = ``fn``)."""
+        return _close(self.shard_apply, self.params)
+
+    def with_params(self, params) -> "Denoiser":
+        """A copy evaluating ``params``: inside a program that takes the
+        weights as an argument, the traced ones."""
+        return dataclasses.replace(self, params=params)
 
     @property
     def is_model_parallel(self) -> bool:
@@ -127,17 +174,17 @@ class Denoiser:
         """Global eval.  Model-parallel denoisers self-wrap ``shard_fn``
         in a shard_map over their bound mesh; plain ones are just ``fn``."""
         if not self.is_model_parallel:
-            return self.fn(x, t)
+            return self.apply(self.params, x, t)
         if self.mesh is None:
             raise ValueError(
                 "model-parallel Denoiser called standalone without a bound "
                 "mesh; use .bind(mesh) or eval inside a driver shard_map "
                 "via .inner_eval()/.shard_eval()")
         wrapped = compat.shard_map(
-            self.shard_fn, mesh=self.mesh,
-            in_specs=(self.in_spec, P()), out_specs=self.out_spec,
+            self.shard_apply, mesh=self.mesh,
+            in_specs=(P(), self.in_spec, P()), out_specs=self.out_spec,
             check_vma=False)
-        return wrapped(x, t)
+        return wrapped(self.params, x, t)
 
     def inner_eval(self) -> Callable:
         """Eval callable for *inside* an enclosing shard_map whose specs
@@ -163,4 +210,4 @@ def as_denoiser(fn) -> Denoiser:
     for values that are already :class:`Denoiser`)."""
     if isinstance(fn, Denoiser):
         return fn
-    return Denoiser(fn=fn)
+    return Denoiser(apply=_Closure(fn))

@@ -2,7 +2,7 @@
 
 Patchified image -> transformer with per-block time-conditioned modulation
 -> unpatchify to an epsilon prediction.  Exposes ``make_denoiser`` returning
-the ``model_fn(x, t)`` closure consumed by every sampler in repro.core.
+the weight-carrying ``Denoiser`` consumed by every sampler in repro.core.
 
 Also provides ``TimeConditioned`` wrapping for any zoo backbone: continuous
 embedding-space diffusion with the backbone as the trunk (how SRDS composes
@@ -120,31 +120,33 @@ def dit_forward(cfg: ArchConfig, params, x_img, t, *, use_kernel=None,
 
 def make_denoiser(cfg: ArchConfig, params, *, use_kernel=None,
                   shard_axis: Optional[str] = None, mesh=None):
-    """Returns model_fn(x, t) with scalar-or-batched t (samplers pass scalar).
+    """The DiT as a :class:`repro.core.denoiser.Denoiser` that carries
+    ``params``: ``apply(params, x, t)`` takes a scalar-or-batched ``t``
+    (samplers pass a scalar), and ``den(x, t)`` evaluates the carried
+    weights.  A program can take the weights as an argument instead of
+    baking them in as constants (the serving engine does).
 
-    With ``shard_axis`` it instead returns a sharding-aware
-    :class:`repro.core.denoiser.Denoiser`: sample rows (the H dim of
-    ``(K, H, W, C)``) patch-shard over that mesh axis
-    (``in_spec = out_spec = P(None, shard_axis)``), the per-shard body is
-    :func:`dit_forward` with its K/V all-gather, and ``fn`` stays the
-    single-device global forward (the bit-exactness reference).  Every
-    driver — ``srds_sample``, the sharded/pipelined samplers, the serving
-    engine — consumes it through the seam with zero DiT-specific code.
-    ``mesh`` (optional) pre-binds the denoiser for standalone calls.
+    With ``shard_axis``, sample rows (the H dim of ``(K, H, W, C)``)
+    patch-shard over that mesh axis (``in_spec = out_spec = P(None,
+    shard_axis)``), the per-shard body is :func:`dit_forward` with its K/V
+    all-gather, and ``apply`` stays the single-device global forward (the
+    bit-exactness reference).  Every driver — ``srds_sample``, the
+    sharded/pipelined samplers, the serving engine — consumes it through
+    the seam with zero DiT-specific code.  ``mesh`` (optional) pre-binds
+    the denoiser for standalone calls.
     """
-
-    def model_fn(x, t):
-        tb = jnp.broadcast_to(jnp.asarray(t, jnp.float32), (x.shape[0],))
-        return dit_forward(cfg, params, x, tb, use_kernel=use_kernel)
-
-    if shard_axis is None:
-        return model_fn
-
     from jax.sharding import PartitionSpec as P
 
     from repro.core.denoiser import Denoiser
 
-    def shard_fn(x, t):
+    def apply(params, x, t):
+        tb = jnp.broadcast_to(jnp.asarray(t, jnp.float32), (x.shape[0],))
+        return dit_forward(cfg, params, x, tb, use_kernel=use_kernel)
+
+    if shard_axis is None:
+        return Denoiser(apply=apply, params=params)
+
+    def shard_apply(params, x, t):
         if x.shape[1] % cfg.patch_size:
             raise ValueError(
                 f"local row-shard of {x.shape[1]} rows is not divisible by "
@@ -154,7 +156,7 @@ def make_denoiser(cfg: ArchConfig, params, *, use_kernel=None,
         return dit_forward(cfg, params, x, tb, use_kernel=use_kernel,
                            shard_axis=shard_axis)
 
-    den = Denoiser(fn=model_fn, shard_fn=shard_fn,
+    den = Denoiser(apply=apply, shard_apply=shard_apply, params=params,
                    in_spec=P(None, shard_axis), out_spec=P(None, shard_axis),
                    mesh_axes={shard_axis: 1})
     return den.bind(mesh) if mesh is not None else den

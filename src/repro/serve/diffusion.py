@@ -129,7 +129,8 @@ annotations, recorded only while a profile is being taken (about a
 microsecond each otherwise): ``serve.admit`` (``rid``, ``waited_ms``),
 ``serve.dispatch`` (``frontier``: the window floor the step ran at),
 ``serve.compile`` (the first call of a program variant, inside
-``serve.dispatch``), ``serve.resolve``
+``serve.dispatch``; ``param_bytes``: the weights it takes as an
+argument), ``serve.resolve``
 (``completed``) and ``serve.fetch`` (every device-to-host transfer).
 Their arguments are host numbers only, never device values.  The
 programs' device phases carry the ``srds.*`` scopes of
@@ -145,7 +146,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import compat
 from repro.analysis.markers import hot_loop
@@ -507,6 +508,7 @@ class _MicroBatch:
         the synchronous engine's.
         """
         K = self.engine.batch_size
+        params = self.engine.params
         init_eff = phys = 0
         if self.newly:
             # coarse-init the fixed batch inside one donated program (the
@@ -517,7 +519,7 @@ class _MicroBatch:
             m[self.newly] = True
             with self._compile_span("init", 0):
                 self.x_tail, self.prev_coarse = self.init_fn(
-                    self.x_init, self.x_tail, self.prev_coarse,
+                    params, self.x_init, self.x_tail, self.prev_coarse,
                     jnp.asarray(m))
             if self.astate is not None:
                 # a recycled slot's mixing history belongs to its previous
@@ -532,7 +534,7 @@ class _MicroBatch:
             self.newly = []
 
         amask = jnp.asarray(self.active)
-        args = (self.x_init, self.x_tail, self.prev_coarse, amask)
+        args = (params, self.x_init, self.x_tail, self.prev_coarse, amask)
         windowed = self.policy.needs_block_residuals
         if windowed:
             # residual-window step: the compiled suffix starts at the
@@ -585,7 +587,8 @@ class _MicroBatch:
         self.step_for.called.add(variant)
         return jax.profiler.TraceAnnotation(
             "serve.compile", frontier=frontier,
-            windowed=int(program == "window"), init=int(program == "init"))
+            windowed=int(program == "window"), init=int(program == "init"),
+            param_bytes=self.engine.param_bytes)
 
     @hot_loop
     def resolve(self, tok: _InFlight):
@@ -801,10 +804,22 @@ class DiffusionSamplingEngine:
                 f"TriangularAccel, or build the engine with truncate=False "
                 f"/ window=FixedBudget().")
         self.use_fused = resolve_fused(use_fused)
+        # every program takes the weights as its argument 0, so its HLO
+        # holds no weights and one executable serves any weights of the
+        # same shapes; they are never donated.  Without a mesh they are
+        # the caller's arrays as they are.  Under a mesh the programs take
+        # them replicated (``_spmd``), so they are placed on every mesh
+        # device once, here: jit would otherwise copy them there on every
+        # call
+        self.params = den.params
+        if mesh is not None and compat.tree.leaves(den.params):
+            self.params = jax.device_put(den.params, NamedSharding(mesh, P()))
+        self.param_bytes = sum(leaf.size * jnp.dtype(leaf.dtype).itemsize
+                               for leaf in compat.tree.leaves(den.params))
         # buffer donation lets XLA reuse the trajectory-sized x_tail /
         # prev_coarse allocations across refinements; the CPU backend
         # ignores donation (with a warning), so only donate off-CPU
-        self._donate = (1, 2) if jax.default_backend() != "cpu" else ()
+        self._donate = (2, 3) if jax.default_backend() != "cpu" else ()
         self.iters_ema = IterationEMA(alpha=ema_alpha)
         if data_axis is not None and mesh is None:
             raise ValueError("data_axis requires a mesh")
@@ -1173,7 +1188,9 @@ class DiffusionSamplingEngine:
         ``step_for(minf)`` returns the jitted one-refinement program whose
         fine solves and corrector sweep are statically truncated to the
         block suffix ``[minf, B)`` (one compiled variant per quantized
-        frontier value, cached).  ``x_tail``/``prev_coarse`` are donated so
+        frontier value, cached).  Every program takes the weights,
+        ``self.params``, as argument 0, so no weight is a constant of its
+        HLO.  ``x_tail``/``prev_coarse`` are donated so
         XLA reuses the trajectory-sized buffers across refinements.
         """
         key = (n, schedule, shape, _solver_fp(solver))
@@ -1190,29 +1207,35 @@ class DiffusionSamplingEngine:
         norm = self.norm
         use_fused = self.use_fused
         accel = self.accel
-        # without a mesh both solves call the seam standalone.  Under a
-        # mesh every program body runs inside one replicated shard_map
-        # (``_spmd``): the coarse sweep takes the seam's inner form (a
-        # model-parallel denoiser slices, runs its shard_fn, gathers), and
-        # the fine solves, whose heads _make_fine slices once per solve,
-        # run shard_fn directly
-        den = self.denoiser
-        coarse_den, fine_den = (den, den) if self.mesh is None \
-            else (den.inner_eval(), den.shard_eval())
+        # both solves evaluate the weights each program takes as its
+        # argument 0 (``params``).  Without a mesh they call the seam
+        # standalone.  Under a mesh every program body runs inside one
+        # replicated shard_map (``_spmd``): the coarse sweep takes the
+        # seam's inner form (a model-parallel denoiser slices, runs its
+        # shard_fn, gathers), and the fine solves, whose heads _make_fine
+        # slices once per solve, run shard_fn directly
+        den, mesh = self.denoiser, self.mesh
 
-        def G(x, i0):
-            return solve(coarse_den, sched, solver, x, i0, 1, S)
+        def solves(params):
+            """(G, fine): the coarse solve and the fine-solve hook."""
+            d = den.with_params(params)
+            coarse_den, fine_den = (d, d) if mesh is None \
+                else (d.inner_eval(), d.shard_eval())
 
-        def F(x, i0):
-            return solve(fine_den, sched, solver, x, i0, S, 1)
+            def G(x, i0):
+                return solve(coarse_den, sched, solver, x, i0, 1, S)
 
-        fine = self._make_fine(F, starts, B)
+            def F(x, i0):
+                return solve(fine_den, sched, solver, x, i0, S, 1)
 
-        def init_body(x_init, x_tail, prev_coarse, new_mask):
+            return G, self._make_fine(F, starts, B)
+
+        def init_body(params, x_init, x_tail, prev_coarse, new_mask):
             # coarse initialization sweep for the whole slot batch, with
             # the new-lane write-back fused in so x_tail/prev_coarse are
             # donated (occupied lanes keep their refined trajectories —
             # the old value flows through the jnp.where)
+            G, _ = solves(params)
             tail0 = coarse_init_sweep(G, x_init, starts)
             m = new_mask.reshape((1,) + new_mask.shape + (1,) * len(shape))
             return (jnp.where(m, tail0, x_tail),
@@ -1225,7 +1248,8 @@ class DiffusionSamplingEngine:
 
         def make_step(minf: int):
             if accel.accelerates:
-                def step_accel(x_init, x_tail, prev_coarse, active, astate):
+                def step_accel(params, x_init, x_tail, prev_coarse, active,
+                               astate):
                     """Accelerated refinement: the unaccelerated step's
                     math, then one :meth:`Accelerator.apply` on the joint
                     state (per-lane, live-masked to the compiled suffix).
@@ -1236,6 +1260,7 @@ class DiffusionSamplingEngine:
                                             axis=0)
                     if minf:
                         heads = heads[minf:]
+                    G, fine = solves(params)
                     y = fine(heads)
                     new_tail, cur_all, _ = suffix_refinement(
                         G, y, x_init, x_tail, prev_coarse, starts, minf,
@@ -1259,10 +1284,10 @@ class DiffusionSamplingEngine:
                     delta = jnp.where(active, delta, jnp.inf)
                     return new_tail, cur_all, delta, astate
 
-                donate = self._donate + (4,) if self._donate else ()
+                donate = self._donate + (5,) if self._donate else ()
                 return jax.jit(self._spmd(step_accel), donate_argnums=donate)
 
-            def step_fn(x_init, x_tail, prev_coarse, active):
+            def step_fn(params, x_init, x_tail, prev_coarse, active):
                 """One Parareal refinement over all K slots, truncated to
                 the suffix [minf, B) via the engine's shared
                 :func:`suffix_refinement`; inactive slots (free, or
@@ -1270,6 +1295,7 @@ class DiffusionSamplingEngine:
                 heads = jnp.concatenate([x_init[None], x_tail[:-1]], axis=0)
                 if minf:
                     heads = heads[minf:]
+                G, fine = solves(params)
                 y = fine(heads)
                 new_tail, cur_all, delta = suffix_refinement(
                     G, y, x_init, x_tail, prev_coarse, starts, minf,
@@ -1286,8 +1312,8 @@ class DiffusionSamplingEngine:
 
         def make_step_windowed(minf: int):
             if accel.accelerates:
-                def step_accel(x_init, x_tail, prev_coarse, active, lo,
-                               astate):
+                def step_accel(params, x_init, x_tail, prev_coarse, active,
+                               lo, astate):
                     """Accelerated residual-window refinement: mixing is
                     live-masked to the dynamic window ``[lo, B)`` —
                     window-frozen blocks stay bitwise untouched — and the
@@ -1298,6 +1324,7 @@ class DiffusionSamplingEngine:
                                             axis=0)
                     if minf:
                         heads = heads[minf:]
+                    G, fine = solves(params)
                     y = fine(heads)
                     new_tail, cur_all, _, _ = suffix_refinement(
                         G, y, x_init, x_tail, prev_coarse, starts, minf,
@@ -1324,10 +1351,10 @@ class DiffusionSamplingEngine:
                     return (new_tail, cur_all,
                             jnp.concatenate([delta, br_g]), astate)
 
-                donate = self._donate + (5,) if self._donate else ()
+                donate = self._donate + (6,) if self._donate else ()
                 return jax.jit(self._spmd(step_accel), donate_argnums=donate)
 
-            def step_fn(x_init, x_tail, prev_coarse, active, lo):
+            def step_fn(params, x_init, x_tail, prev_coarse, active, lo):
                 """One residual-window refinement over all K slots: the
                 compiled suffix is [minf, B), blocks [minf, lo) freeze by
                 masking inside the engine's shared
@@ -1337,6 +1364,7 @@ class DiffusionSamplingEngine:
                 heads = jnp.concatenate([x_init[None], x_tail[:-1]], axis=0)
                 if minf:
                     heads = heads[minf:]
+                G, fine = solves(params)
                 y = fine(heads)
                 new_tail, cur_all, delta, br = suffix_refinement(
                     G, y, x_init, x_tail, prev_coarse, starts, minf,

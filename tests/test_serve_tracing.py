@@ -158,6 +158,33 @@ def test_admission_counts_by_hand(tmp_path):
     assert sum(r["scanned"] for r in rounds) == counts["scanned"]
 
 
+def _tiny_dit_params():
+    cfg = dataclasses.replace(get_arch("srds-dit-cifar"), num_layers=2,
+                              d_model=64, num_heads=4, num_kv_heads=4,
+                              d_ff=256)
+    return cfg, init_dit(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("model", ["plain", "dit"])
+def test_compile_span_carries_param_bytes(tmp_path, model):
+    """``serve.compile`` gives the bytes of weights each program takes as
+    an argument: the DiT's weight bytes for ``dit_denoiser``, 0 for a
+    plain callable, which carries none."""
+    if model == "plain":
+        engine, want = _engine(VirtualClock()), 0
+    else:
+        cfg, params = _tiny_dit_params()
+        want = sum(a.nbytes for a in jax.tree.leaves(params))
+        engine = DiffusionSamplingEngine(
+            dit_denoiser(cfg, params, use_kernel=False), (32, 32, 3),
+            SolverConfig("ddim"), num_steps=25, batch_size=2, num_blocks=5)
+    reqs = [SampleRequest(seed=i, tol=1e-2) for i in range(2)]
+    _, spans = _spans(tmp_path, lambda: AsyncServeLoop(engine).run(reqs))
+    compiles = [s[3] for s in spans if s[0] == "serve.compile"]
+    assert compiles and {c["init"] for c in compiles} == {0.0, 1.0}
+    assert all(c["param_bytes"] == want for c in compiles)
+
+
 def _stack_files(text):
     """``stack_frame_id -> set of file names`` on the frame's chain, from
     the tables at the head of a compiled module's text."""
@@ -201,10 +228,7 @@ def _dit_op_scopes(text):
 
 @pytest.fixture(scope="module")
 def dit_engine():
-    cfg = dataclasses.replace(get_arch("srds-dit-cifar"), num_layers=2,
-                              d_model=64, num_heads=4, num_kv_heads=4,
-                              d_ff=256)
-    params = init_dit(cfg, jax.random.PRNGKey(0))
+    cfg, params = _tiny_dit_params()
     eng = DiffusionSamplingEngine(dit_denoiser(cfg, params), (32, 32, 3),
                                   SolverConfig("ddim"), num_steps=25,
                                   batch_size=2, num_blocks=5)
@@ -220,8 +244,8 @@ def dit_engine():
 def test_every_dit_op_carries_one_scope(dit_engine, program, want):
     (b,) = dit_engine._batches.values()
     fn = b.init_fn if program == "init" else b.step_for(0)
-    text = fn.lower(b.x_init, b.x_tail, b.prev_coarse,
-                    jnp.asarray(b.active)).compile().as_text()
+    text = fn.lower(dit_engine.denoiser.params, b.x_init, b.x_tail,
+                    b.prev_coarse, jnp.asarray(b.active)).compile().as_text()
     ops = _dit_op_scopes(text)
     kinds = collections.Counter(k for k, _ in ops)
     assert kinds["dot"] > 0 and kinds["fusion"] > 0
